@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 DEFAULT_BM, DEFAULT_BN, DEFAULT_BK = 128, 128, 512
 
@@ -53,12 +52,12 @@ def quant_matmul(x_q, w_q, sx, sw, *, bm=DEFAULT_BM, bn=DEFAULT_BN,
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
-            pl.BlockSpec(memory_space=pl.ANY),  # sx scalar, full
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # sx scalar
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_q, w_q, sw.reshape(1, n), sx.reshape(1))

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 from repro.kernels.ternary_packed import unpack_ternary
 
 DEFAULT_BM, DEFAULT_BN, DEFAULT_BK = 128, 128, 512
@@ -96,12 +95,12 @@ def split_ternary_matmul(x_q, w_q, w_packed, sx, sw, boundary, *,
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((bk // 4, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_q, w_q, w_packed, sw.reshape(1, n), sx.reshape(1))

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 DEFAULT_BM, DEFAULT_BN, DEFAULT_BK = 128, 128, 512
 
@@ -33,10 +32,13 @@ def pack_ternary(w_t: jax.Array) -> jax.Array:
 
 
 def unpack_ternary(w_p: jax.Array) -> jax.Array:
-    """(K//4, N) uint8 -> (K, N) int8 codes (jnp reference)."""
+    """(K//4, N) uint8 -> (K, N) int8 codes; the kernels unpack with this."""
     Kp, N = w_p.shape
-    parts = [((w_p >> (2 * j)) & 3).astype(jnp.int8) - 1 for j in range(4)]
-    return jnp.stack(parts, axis=1).reshape(Kp * 4, N)
+    # Shift, mask and bias in int32: the TPU vector unit has no int8
+    # arithmetic, so the only int8 op is the final cast for the MXU.
+    w = w_p.astype(jnp.int32)
+    parts = [((w >> (2 * j)) & 3) - 1 for j in range(4)]
+    return jnp.stack(parts, axis=1).reshape(Kp * 4, N).astype(jnp.int8)
 
 
 def _kernel(x_ref, wp_ref, sw_ref, sx_ref, o_ref, acc_ref, *, nk: int):
@@ -44,9 +46,7 @@ def _kernel(x_ref, wp_ref, sw_ref, sx_ref, o_ref, acc_ref, *, nk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    wp = wp_ref[...]                                # (bk//4, bn) uint8
-    parts = [((wp >> (2 * j)) & 3).astype(jnp.int8) - 1 for j in range(4)]
-    w = jnp.stack(parts, axis=1).reshape(wp.shape[0] * 4, wp.shape[1])
+    w = unpack_ternary(wp_ref[...])                 # (bk//4, bn) -> (bk, bn)
     acc_ref[...] += jax.lax.dot_general(
         x_ref[...], w, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32)
@@ -70,12 +70,12 @@ def ternary_packed_matmul(x_q, w_packed, sx, sw, *, bm=DEFAULT_BM,
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk // 4, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_q, w_packed, sw.reshape(1, n), sx.reshape(1))

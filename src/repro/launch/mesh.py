@@ -7,6 +7,7 @@ XLA_FLAGS before any import) sees 512 host devices.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,7 +25,8 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {need} devices, have {len(devs)}; the "
             "dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before importing jax")
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return jax.make_mesh(shape, axes, devices=devs[:need],
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def mesh_axes(mesh) -> tuple:
@@ -37,4 +39,5 @@ def mesh_axes(mesh) -> tuple:
 
 def smoke_mesh():
     """1-device mesh for CPU tests of the sharding machinery."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))

@@ -114,8 +114,7 @@ def apply_mapping_artifact(cfg, artifact):
     the first-class path is per-layer planned execution via
     `plan_mapping_execution`.
     """
-    fractions = artifact.domain_channel_fractions(searchable_only=True)
-    dom = artifact.domains[int(np.argmax(fractions))]
+    dom = _majority_domain(artifact)
     updates = {}
     if dom["weight_bits"] <= 8:
         updates["serve_weight_dtype"] = "int8"
@@ -124,6 +123,20 @@ def apply_mapping_artifact(cfg, artifact):
     if updates:
         cfg = dataclasses.replace(cfg, **updates)
     return cfg, dom
+
+
+def _majority_domain(artifact):
+    fractions = artifact.domain_channel_fractions(searchable_only=True)
+    return artifact.domains[int(np.argmax(fractions))]
+
+
+def planned_kv_cfg(cfg, artifact):
+    """The cfg the planned path serves with: the weight kernels don't cover
+    the KV cache, so it is int8 when the artifact's majority domain
+    quantizes activations, as on the fallback path."""
+    if _majority_domain(artifact).get("act_bits", 16) <= 8:
+        cfg = dataclasses.replace(cfg, kv_cache_dtype="int8")
+    return cfg
 
 
 def plan_mapping_execution(params, artifact, interpret=None):
@@ -694,12 +707,9 @@ def main(argv=None):
             print(f"[serve] multi-plan bank failed to lower/bind ({e})",
                   file=sys.stderr)
             sys.exit(2)
-        # KV-cache precision follows the default/target artifact's
-        # activation majority, as on the single-plan path
-        fractions = art.domain_channel_fractions(searchable_only=True)
-        dom = art.domains[int(np.argmax(fractions))]
-        if dom.get("act_bits", 16) <= 8:
-            cfg = dataclasses.replace(cfg, kv_cache_dtype="int8")
+        # the default/target artifact sets the KV cache, as on the
+        # single-plan path
+        cfg = planned_kv_cfg(cfg, art)
         print(f"[serve] planset bank: model={art.model} "
               f"platform={art.platform} "
               f"variants={list(backend.variant_names)} default={default!r} "
@@ -709,18 +719,17 @@ def main(argv=None):
         from repro.runtime import ExecutionError, LoweringError
         plan = None
         if not args.mapping_fallback:
+            t0 = time.perf_counter()
             try:
                 plan, backend = plan_mapping_execution(params, art)
             except (LoweringError, ExecutionError) as e:
                 print(f"[serve] mapping {args.mapping} failed to lower/bind "
                       f"({e}); falling back to majority-dtype serving")
+            else:
+                print(f"[serve] lowered and bound in "
+                      f"{time.perf_counter() - t0:.3f}s")
         if backend is not None:
-            # KV-cache precision follows the artifact's activation majority
-            # even on the planned path (the weight kernels don't cover it)
-            fractions = art.domain_channel_fractions(searchable_only=True)
-            dom = art.domains[int(np.argmax(fractions))]
-            if dom.get("act_bits", 16) <= 8:
-                cfg = dataclasses.replace(cfg, kv_cache_dtype="int8")
+            cfg = planned_kv_cfg(cfg, art)
             print(f"[serve] mapping {args.mapping}: model={art.model} "
                   f"platform={art.platform} kv={cfg.kv_cache_dtype} "
                   f"(jit: prefill+decode)")
@@ -763,4 +772,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -1,7 +1,6 @@
 """End-to-end training driver.
 
-Runs any registered arch (full or reduced), with:
-  * mesh + FSDP/TP shardings (1-device mesh on CPU works transparently)
+Runs any registered arch (full or reduced) on one device, with:
   * deterministic restart-safe data pipeline
   * atomic async checkpointing + restore (resume with --resume)
   * straggler monitoring + non-finite-step skipping (TrainSupervisor logic)
@@ -26,7 +25,6 @@ import numpy as np
 from repro.checkpoint import checkpoint as ckpt
 from repro.configs import base as cfgbase
 from repro.data.pipeline import ShardedLoader, TokenTaskConfig
-from repro.distributed import sharding as sh
 from repro.distributed.fault_tolerance import StragglerPolicy
 from repro.models import transformer as T
 from repro.optim import adamw, compression
@@ -344,4 +342,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

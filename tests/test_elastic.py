@@ -5,6 +5,7 @@ verify the loss trajectory matches an uninterrupted run bit-for-bit.
 Runs in a subprocess with 8 forced host devices (the test process itself
 keeps 1 device; see dryrun.py's device-count note).
 """
+import os
 import subprocess
 import sys
 import textwrap
@@ -18,7 +19,7 @@ SCRIPT = textwrap.dedent("""
     import sys
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.checkpoint import checkpoint as ckpt
     from repro.configs import base
     from repro.data.pipeline import ShardedLoader, TokenTaskConfig
@@ -59,7 +60,8 @@ SCRIPT = textwrap.dedent("""
     opt = adamw.init(params, ocfg)
 
     # --- reference: 6 uninterrupted steps on the BIG mesh (8 devices) ---
-    mesh8 = jax.make_mesh((8,), ("data",), devices=jax.devices()[:8])
+    mesh8 = jax.make_mesh((8,), ("data",), devices=jax.devices()[:8],
+                          axis_types=(AxisType.Auto,))
     p_ref, o_ref, losses_ref = run(put(params, mesh8), put(opt, mesh8),
                                    mesh8, 0, 6)
 
@@ -67,13 +69,14 @@ SCRIPT = textwrap.dedent("""
     plan = ElasticPlan(old_shape=(8, 1), new_hosts=1, chips_per_host=4)
     assert plan.needs_reshard
     p1, o1, losses_a = run(put(params, mesh8), put(opt, mesh8), mesh8, 0, 3)
-    ckpt.save("/tmp/elastic_ckpt", 3, (p1, o1), {"step": 3})
+    ckpt.save(sys.argv[1], 3, (p1, o1), {"step": 3})
 
-    mesh4 = jax.make_mesh((4,), ("data",), devices=jax.devices()[:4])
+    mesh4 = jax.make_mesh((4,), ("data",), devices=jax.devices()[:4],
+                          axis_types=(AxisType.Auto,))
     like = (p1, o1)
     rep4 = jax.tree.map(
         lambda x: NamedSharding(mesh4, P()), like)
-    p2, o2 = ckpt.restore("/tmp/elastic_ckpt", 3, like, shardings=rep4)
+    p2, o2 = ckpt.restore(sys.argv[1], 3, like, shardings=rep4)
     data.reshard(shard=0, n_shards=1)  # deterministic stream continues
     _, _, losses_b = run(p2, o2, mesh4, 3, 6)
 
@@ -85,7 +88,9 @@ SCRIPT = textwrap.dedent("""
 
 @pytest.mark.slow
 def test_elastic_rescale_roundtrip(tmp_path):
-    out = subprocess.run([sys.executable, "-c", SCRIPT],
+    # the child forces 8 host devices and must never reach for a chip
+    out = subprocess.run([sys.executable, "-c", SCRIPT, str(tmp_path / "ckpt")],
                          cwd=Path(__file__).resolve().parents[1],
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"},
                          capture_output=True, text=True, timeout=1200)
     assert "ELASTIC_OK" in out.stdout, out.stdout[-2000:] + out.stderr[-3000:]

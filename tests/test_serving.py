@@ -242,15 +242,23 @@ def test_engine_retirement_and_admission():
     mk = lambda i, plen, new, **kw: Request(
         rid=i, prompt=rng.integers(0, cfg.vocab, size=plen),
         max_new_tokens=new, **kw)
-    # learn a token to use as EOS for request 1
+    # learn a token to use as EOS for request 1: the first decoded token
+    # that did not occur earlier in the stream, so EOS fires exactly there
+    # (a random model may repeat one token, so draw prompts until one works)
     probe = Engine(cfg, params, max_batch=1, max_len=16)
-    r1 = mk(1, 5, 6)
-    probe_tok = probe.run([Request(rid="p", prompt=r1.prompt,
-                                   max_new_tokens=2)])[0].tokens
+    for _ in range(8):
+        r1 = mk(1, 5, 6)
+        probe_tok = probe.run([Request(rid="p", prompt=r1.prompt,
+                                       max_new_tokens=6)])[0].tokens
+        eos_at = next((i for i in range(1, len(probe_tok))
+                       if probe_tok[i] not in probe_tok[:i]), None)
+        if eos_at is not None:
+            break
+    assert eos_at is not None, probe_tok
     reqs = [
         mk(0, 4, 1),                                   # retires at admission
         Request(rid=1, prompt=r1.prompt, max_new_tokens=6,
-                eos_id=int(probe_tok[1])),             # retires on EOS
+                eos_id=int(probe_tok[eos_at])),        # retires on EOS
         mk(2, 14, 8),                                  # hits the length cap
         mk(3, 3, 4),                                   # fills a freed slot
         mk(4, 3, 3, arrival_step=2),                   # late arrival
@@ -259,7 +267,8 @@ def test_engine_retirement_and_admission():
     res = {r.rid: r for r in eng.run(reqs)}
     assert res[0].finish_reason == "max_new_tokens" and res[0].n_tokens == 1
     assert res[0].finished_step == res[0].admitted_step   # no decode needed
-    assert res[1].finish_reason == "eos" and res[1].n_tokens == 2
+    assert res[1].finish_reason == "eos" and res[1].n_tokens == eos_at + 1
+    assert res[1].tokens == list(probe_tok[:eos_at + 1])
     assert res[2].finish_reason == "length_cap"
     assert res[2].prompt_len + res[2].n_tokens - 1 == 16  # pool exhausted
     assert res[3].finish_reason == "max_new_tokens" and res[3].n_tokens == 4

@@ -17,14 +17,13 @@ Components:
                        committed step after a (simulated) crash, skips
                        non-finite gradient steps, and records every event
 
-The serving engine (`repro.serving.engine`) reuses HeartbeatMonitor and
-StragglerPolicy at INFERENCE time: each decode slot is a "host" beating on
-every committed token, with the monitor's clock bound to the engine step
-counter — a slot silent for ``heartbeat_steps`` steps (a stuck fault) is
-quarantined and its request requeued; StragglerPolicy flags outlier decode
-steps into ``engine.stats["straggler_events"]``.  Both are clock-agnostic
-by construction (``clock`` is injectable), which is what makes the same
-logic serve wall-clock training and step-clock inference.
+The serving engine (`repro.serving.engine`) reuses HeartbeatMonitor at
+INFERENCE time: each decode slot is a "host" beating on every committed
+token, with the monitor's clock bound to the engine step counter — a slot
+silent for ``heartbeat_steps`` steps (a stuck fault) is quarantined and its
+request requeued.  The monitor is clock-agnostic by construction
+(``clock`` is injectable), which is what makes the same logic serve
+wall-clock training and step-clock inference.
 """
 from __future__ import annotations
 

@@ -120,9 +120,32 @@ overload and numerical faults instead of degrading unboundedly:
     it with ``ShedResult(reason="fault")``.  Stuck slots — which commit
     nothing, so the logit screen cannot see them — are caught by the
     `repro.distributed.fault_tolerance.HeartbeatMonitor` running on the
-    engine's step clock (slots beat on token commit / chunk progress); a
-    `StragglerPolicy` EMA over decode-step wall times records outlier
-    steps in ``stats["straggler_events"]``.
+    engine's step clock (slots beat on token commit / chunk progress).
+
+SPANS — each phase of the loop runs inside `Engine._span`, which writes a
+`jax.profiler.TraceAnnotation` (under a profiler it lands on the host
+plane of the device trace, on its clock) and adds the phase's wall time to
+``stats`` under the key `span_key` derives from the name
+(``engine.decode.wait`` -> ``engine_decode_wait_s``).  The tree, the same
+on every path:
+
+  engine.run
+    engine.step               one loop iteration
+      engine.schedule         timeouts, preemption, admissions, shedding,
+                              fault injection
+      engine.admit            slot bookkeeping, page allocation, slot reset
+                              and copy-on-write dispatches (dense: the
+                              prompt batch)
+      engine.chunk            prompt prefill (chunked; dense: one call)
+        engine.chunk.dispatch   the jitted calls returning
+        engine.chunk.wait       host syncs on their outputs
+      engine.decode           one decode step (speculative: one round)
+        engine.decode.dispatch
+        engine.decode.wait
+        engine.decode.commit  token commit, retirement, fault handling
+
+``stats["decode_rows"]`` counts the slots each decode call ran, and
+``RequestResult.queue_s`` is a request's wait for its first admission.
 """
 from __future__ import annotations
 
@@ -137,8 +160,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.distributed.fault_tolerance import HeartbeatMonitor, \
-    StragglerPolicy
+from repro.distributed.fault_tolerance import HeartbeatMonitor
 from repro.models import transformer as T
 from repro.models.managed import matmul_backend
 from repro.serving.batch import BatchState
@@ -155,6 +177,32 @@ KV_LAYOUTS = ("paged", "dense")
 # prefix sharing is only sound when ALL sequence state is page-resident
 # (pure attention KV) and per-token compute is batch-composition-free
 _PREFIX_SAFE_KINDS = frozenset({"attn", "shared_attn", "mla"})
+
+
+def span_key(name: str) -> str:
+    """The ``Engine.stats`` key of span ``name``: ``engine.decode.wait``
+    -> ``engine_decode_wait_s``."""
+    return name.replace(".", "_") + "_s"
+
+
+class _Span:
+    """`Engine._span`'s context manager; a plain class because a
+    generator-based one costs about twice as much per span."""
+    __slots__ = ("stats", "key", "ann", "t0")
+
+    def __init__(self, stats: Dict[str, float], name: str):
+        self.stats = stats
+        self.key = span_key(name)
+        self.ann = jax.profiler.TraceAnnotation(name)
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self.t0
+        self.ann.__exit__(*exc)
+        self.stats[self.key] = self.stats.get(self.key, 0.0) + dt
 
 
 class Engine:
@@ -637,6 +685,12 @@ class Engine:
         return (matmul_backend(self.backend) if self.backend is not None
                 else contextlib.nullcontext())
 
+    def _span(self, name: str) -> "_Span":
+        """Profiler annotation ``name`` around a block, its wall time added
+        to ``stats[span_key(name)]`` (see SPANS in the module docstring).
+        ``name`` is a constant: the trace and the stats key it by name."""
+        return _Span(self.stats, name)
+
     def _pages_needed(self, req: Request) -> int:
         total = min(req.prompt_len + req.max_new_tokens, self.slot_cap)
         return self.pool_mgr.pages_for(total)
@@ -665,14 +719,15 @@ class Engine:
         ``variant``/``degraded`` are pinned here and reused on every
         resume — a request's KV numerics must stay under one variant for
         its whole lifetime.  ``tokens``/``t_first`` hold the committed
-        state a preempted/faulted request resumes from."""
+        state a preempted/faulted request resumes from; ``t_admit`` is
+        when it first took a slot (its queue wait ends there)."""
         meta = self._req_meta.get(id(req))
         if meta is None:
             degraded = self._degrade is not None and self._degrade.active
             meta = {"variant": (self.degrade_to if degraded
                                 else self._route(req)),
                     "degraded": degraded, "tokens": [], "t_first": None,
-                    "preemptions": 0, "requeues": 0}
+                    "t_admit": None, "preemptions": 0, "requeues": 0}
             self._req_meta[id(req)] = meta
         return meta
 
@@ -740,6 +795,7 @@ class Engine:
             finish_reason=reason, ttft_s=st.t_first - st.t_ready,
             finish_s=now - st.t_ready, admitted_step=st.admitted_step,
             finished_step=step, slo=req.slo,
+            queue_s=meta.get("t_admit", st.t_ready) - st.t_ready,
             variant=meta.get("variant"),
             degraded=bool(meta.get("degraded", False)),
             preemptions=int(meta.get("preemptions", 0)),
@@ -796,43 +852,51 @@ class Engine:
 
     def _admit_dense(self, batch: BatchState, admits, step: int,
                      t_ready: Dict[int, float]):
-        slots = np.asarray([s for s, _ in admits], np.int32)
+        """Prefill an admission group in one call (the dense path's
+        ``engine.chunk``) and assign its slots."""
         reqs = [r for _, r in admits]
-        k = len(reqs)
-        kp = self._gbucket(k)                 # pad the GROUP SIZE too
-        P = self._bucket(max(r.prompt_len for r in reqs))
-        prompts = np.zeros((kp, P), np.int32)
-        lengths = np.zeros(kp, np.int32)
-        keys = np.zeros((kp, 2), np.uint32)
-        for i, r in enumerate(reqs):
-            prompts[i, :r.prompt_len] = r.prompt
-            lengths[i] = r.prompt_len
-            keys[i] = self._next_key()
-        # pad rows repeat the last real request (identical rows compute
-        # identical caches, so the duplicate scatter writes are no-ops)
-        prompts[k:] = prompts[k - 1]
-        lengths[k:] = lengths[k - 1]
-        slots_p = np.concatenate([slots, np.full(kp - k, slots[-1],
-                                                 np.int32)])
-        frontend = None
-        if self.cfg.frontend:
-            rows = [self._frontend_row(r) for r in reqs]
-            frontend = jnp.stack(rows + [rows[-1]] * (kp - k))
-        t0 = time.monotonic()
-        tok0, keys_out, batch.caches = self._prefill(
-            self.params, prompts, lengths, batch.caches, slots_p, frontend,
-            keys)
-        tok0 = np.asarray(tok0)           # sync: first tokens materialized
-        if self.sampling is not None:
-            keys_out = np.asarray(keys_out)
-        t1 = time.monotonic()
-        self.stats["prefill_s"] += t1 - t0
-        self.stats["prefill_calls"] += 1
-        for i, (slot, req) in enumerate(admits):
-            batch.assign(slot, req, int(tok0[i]),
-                         t_ready=t_ready[id(req)], t_first=t1, step=step)
-            if self.sampling is not None:
-                batch.rng[slot] = keys_out[i]
+        with self._span("engine.admit"):
+            slots = np.asarray([s for s, _ in admits], np.int32)
+            k = len(reqs)
+            kp = self._gbucket(k)                 # pad the GROUP SIZE too
+            P = self._bucket(max(r.prompt_len for r in reqs))
+            prompts = np.zeros((kp, P), np.int32)
+            lengths = np.zeros(kp, np.int32)
+            keys = np.zeros((kp, 2), np.uint32)
+            for i, r in enumerate(reqs):
+                prompts[i, :r.prompt_len] = r.prompt
+                lengths[i] = r.prompt_len
+                keys[i] = self._next_key()
+            # pad rows repeat the last real request (identical rows compute
+            # identical caches, so the duplicate scatter writes are no-ops)
+            prompts[k:] = prompts[k - 1]
+            lengths[k:] = lengths[k - 1]
+            slots_p = np.concatenate([slots, np.full(kp - k, slots[-1],
+                                                     np.int32)])
+            frontend = None
+            if self.cfg.frontend:
+                rows = [self._frontend_row(r) for r in reqs]
+                frontend = jnp.stack(rows + [rows[-1]] * (kp - k))
+        with self._span("engine.chunk"):
+            t0 = time.monotonic()
+            for r in reqs:
+                self._meta(r)["t_admit"] = t0
+            with self._span("engine.chunk.dispatch"):
+                tok0, keys_out, batch.caches = self._prefill(
+                    self.params, prompts, lengths, batch.caches, slots_p,
+                    frontend, keys)
+            with self._span("engine.chunk.wait"):
+                tok0 = np.asarray(tok0)       # sync: first tokens
+                if self.sampling is not None:
+                    keys_out = np.asarray(keys_out)
+            t1 = time.monotonic()
+            self.stats["prefill_s"] += t1 - t0
+            self.stats["prefill_calls"] += 1
+            for i, (slot, req) in enumerate(admits):
+                batch.assign(slot, req, int(tok0[i]),
+                             t_ready=t_ready[id(req)], t_first=t1, step=step)
+                if self.sampling is not None:
+                    batch.rng[slot] = keys_out[i]
         return [s for s, _ in admits]
 
     # ---- paged admission + chunked prefill -------------------------------
@@ -841,8 +905,11 @@ class Engine:
                      t_ready: Dict[int, float]):
         cow_pairs = []
         slots = []
+        now = time.monotonic()
         for slot, req in admits:
             meta = self._meta(req)
+            if meta["t_admit"] is None:
+                meta["t_admit"] = now
             prompt = self._eff_prompt(req)
             if meta["tokens"]:
                 self.stats["resumes"] += 1
@@ -919,25 +986,27 @@ class Engine:
             valid_all[b] = n
         t0 = time.monotonic()
         outs = []
-        for var, group in self._variant_groups(batch, sel):
-            valid = np.zeros(B, np.int32)
-            valid[group] = valid_all[group]
-            tok, keys, ok, batch.caches = self._chunk(
-                self.params, tokens, batch.caches, batch.fill_pos.copy(),
-                valid, batch.page_table.copy(), self._fe_buf, batch.rng,
-                variant=var)
-            outs.append((group, tok, keys, ok))
-            self.stats["prefill_calls"] += 1
+        with self._span("engine.chunk.dispatch"):
+            for var, group in self._variant_groups(batch, sel):
+                valid = np.zeros(B, np.int32)
+                valid[group] = valid_all[group]
+                tok, keys, ok, batch.caches = self._chunk(
+                    self.params, tokens, batch.caches, batch.fill_pos.copy(),
+                    valid, batch.page_table.copy(), self._fe_buf, batch.rng,
+                    variant=var)
+                outs.append((group, tok, keys, ok))
+                self.stats["prefill_calls"] += 1
         tok_all = np.zeros(B, np.int32)
         ok_all = np.ones(B, bool)
         keys_all = None
-        for group, tok, keys, ok in outs:
-            tok_all[group] = np.asarray(tok)[group]     # sync
-            ok_all[group] = np.asarray(ok)[group]
-            if self.sampling is not None:
-                if keys_all is None:
-                    keys_all = np.zeros((B, 2), np.uint32)
-                keys_all[group] = np.asarray(keys)[group]
+        with self._span("engine.chunk.wait"):
+            for group, tok, keys, ok in outs:
+                tok_all[group] = np.asarray(tok)[group]     # sync
+                ok_all[group] = np.asarray(ok)[group]
+                if self.sampling is not None:
+                    if keys_all is None:
+                        keys_all = np.zeros((B, 2), np.uint32)
+                    keys_all[group] = np.asarray(keys)[group]
         t1 = time.monotonic()
         self.stats["prefill_s"] += t1 - t0
         batch.fill_pos[sel] += valid_all[sel]
@@ -992,33 +1061,38 @@ class Engine:
         inject[self._inject_slots] = np.nan
         self._inject_slots = []
         outs = []
-        for var, group in self._variant_groups(
-                batch, np.nonzero(batch.active & ~stuck)[0]):
-            mask = np.zeros(self.max_batch, bool)
-            mask[group] = True
-            tok, keys, ok, batch.caches = self._decode_paged(
-                self.params, batch.last_tok, batch.caches, batch.lengths,
-                mask, batch.page_table.copy(), batch.rng, inject,
-                variant=var)
-            outs.append((group, tok, keys, ok))
+        with self._span("engine.decode.dispatch"):
+            for var, group in self._variant_groups(
+                    batch, np.nonzero(batch.active & ~stuck)[0]):
+                mask = np.zeros(self.max_batch, bool)
+                mask[group] = True
+                tok, keys, ok, batch.caches = self._decode_paged(
+                    self.params, batch.last_tok, batch.caches, batch.lengths,
+                    mask, batch.page_table.copy(), batch.rng, inject,
+                    variant=var)
+                outs.append((group, tok, keys, ok))
+                self.stats["decode_rows"] += len(group)
         tok_all = batch.last_tok.copy()
         ok_all = np.ones(self.max_batch, bool)
-        for group, tok, keys, ok in outs:
-            tok_all[group] = np.asarray(tok)[group]     # sync
-            ok_all[group] = np.asarray(ok)[group]
-            if self.sampling is not None:
-                batch.rng[group] = np.asarray(keys)[group]
+        with self._span("engine.decode.wait"):
+            for group, tok, keys, ok in outs:
+                tok_all[group] = np.asarray(tok)[group]     # sync
+                ok_all[group] = np.asarray(ok)[group]
+                if self.sampling is not None:
+                    batch.rng[group] = np.asarray(keys)[group]
         now = time.monotonic()
         self.stats["decode_s"] += now - t
         self.stats["decode_steps"] += 1
-        faulted = batch.active & ~ok_all & ~stuck
-        self._postdecode(batch, tok_all, now, step, results,
-                         exclude=(stuck | faulted))
-        if queue is not None:
-            for b in np.nonzero(faulted)[0]:
-                if batch.active[b]:     # not retired by _postdecode
-                    self._handle_fault(batch, queue, int(b), step, now,
-                                       t_ready or {}, results, purge=True)
+        with self._span("engine.decode.commit"):
+            faulted = batch.active & ~ok_all & ~stuck
+            self._postdecode(batch, tok_all, now, step, results,
+                             exclude=(stuck | faulted))
+            if queue is not None:
+                for b in np.nonzero(faulted)[0]:
+                    if batch.active[b]:     # not retired by _postdecode
+                        self._handle_fault(batch, queue, int(b), step, now,
+                                           t_ready or {}, results,
+                                           purge=True)
 
     # ---- self-speculative decoding ---------------------------------------
 
@@ -1036,56 +1110,62 @@ class Engine:
         fill0 = batch.lengths.copy()
         snap = batch.caches                  # pre-draft arrays (immutable)
         t = time.monotonic()
-        drafted, batch.caches = self._draft(
-            self.params, tok0, batch.caches, fill0, batch.active.copy(),
-            batch.page_table.copy())
-        vcount = np.zeros(self.max_batch, np.int32)
-        vcount[sel] = np.minimum(k + 1, self.slot_cap - fill0[sel])
-        vtok, batch.caches = self._verify(
-            self.params, tok0, drafted, batch.caches, snap, fill0, vcount,
-            batch.page_table.copy())
-        d = np.asarray(drafted)              # sync (both calls dispatched)
-        v = np.asarray(vtok)
+        with self._span("engine.decode.dispatch"):
+            drafted, batch.caches = self._draft(
+                self.params, tok0, batch.caches, fill0, batch.active.copy(),
+                batch.page_table.copy())
+            vcount = np.zeros(self.max_batch, np.int32)
+            vcount[sel] = np.minimum(k + 1, self.slot_cap - fill0[sel])
+            vtok, batch.caches = self._verify(
+                self.params, tok0, drafted, batch.caches, snap, fill0,
+                vcount, batch.page_table.copy())
+        self.stats["decode_rows"] += len(sel)
+        with self._span("engine.decode.wait"):
+            d = np.asarray(drafted)          # sync (both calls dispatched)
+            v = np.asarray(vtok)
         now = time.monotonic()
         self.stats["decode_s"] += now - t
         self.stats["decode_steps"] += 1
         self.stats["spec_rounds"] += 1
         replay_valid = np.zeros(self.max_batch, np.int32)
-        for b in sel:
-            vc = int(vcount[b])
-            # drafts that could actually commit: the slot's remaining token
-            # budget caps the round, so over-drafting past it is not a
-            # draft-quality failure and must not dilute the acceptance rate
-            budget_left = int(batch.max_new[b] - batch.n_gen[b])
-            m = 0                            # agreeing draft prefix
-            while m < vc - 1 and d[b, m] == v[b, m]:
-                m += 1
-            st = batch.slots[b]
-            committed = 0
-            retired = False
-            for j in range(m + 1):           # m matches + 1 bonus token
-                tokj = int(v[b, j])
-                st.tokens.append(tokj)
-                batch.last_tok[b] = tokj
-                batch.lengths[b] += 1
-                batch.n_gen[b] += 1
-                committed += 1
-                reason = self._slot_reason(batch, int(b))
-                if reason is not None:
-                    self._retire_slot(batch, int(b), reason, now, step,
-                                      results)
-                    retired = True
-                    break
-            self.stats["spec_drafted"] += min(vc - 1, budget_left)
-            self.stats["spec_accepted"] += min(committed, m)
-            self.stats["spec_committed"] += committed
-            if not retired and committed < vc:
-                replay_valid[b] = committed
+        with self._span("engine.decode.commit"):
+            for b in sel:
+                vc = int(vcount[b])
+                # drafts that could actually commit: the slot's remaining
+                # token budget caps the round, so over-drafting past it is
+                # not a draft-quality failure and must not dilute the
+                # acceptance rate
+                budget_left = int(batch.max_new[b] - batch.n_gen[b])
+                m = 0                        # agreeing draft prefix
+                while m < vc - 1 and d[b, m] == v[b, m]:
+                    m += 1
+                st = batch.slots[b]
+                committed = 0
+                retired = False
+                for j in range(m + 1):       # m matches + 1 bonus token
+                    tokj = int(v[b, j])
+                    st.tokens.append(tokj)
+                    batch.last_tok[b] = tokj
+                    batch.lengths[b] += 1
+                    batch.n_gen[b] += 1
+                    committed += 1
+                    reason = self._slot_reason(batch, int(b))
+                    if reason is not None:
+                        self._retire_slot(batch, int(b), reason, now, step,
+                                          results)
+                        retired = True
+                        break
+                self.stats["spec_drafted"] += min(vc - 1, budget_left)
+                self.stats["spec_accepted"] += min(committed, m)
+                self.stats["spec_committed"] += committed
+                if not retired and committed < vc:
+                    replay_valid[b] = committed
         if self._has_recurrent and replay_valid.any():
             t = time.monotonic()
-            batch.caches = self._replay(
-                self.params, tok0, drafted, batch.caches, snap, fill0,
-                replay_valid, batch.page_table.copy())
+            with self._span("engine.decode.dispatch"):
+                batch.caches = self._replay(
+                    self.params, tok0, drafted, batch.caches, snap, fill0,
+                    replay_valid, batch.page_table.copy())
             self.stats["decode_s"] += time.monotonic() - t
 
     # ---- robustness: preemption, shedding, faults ------------------------
@@ -1297,7 +1377,7 @@ class Engine:
                       "preemptions": 0, "resumes": 0, "shed_requests": 0,
                       "timeouts": 0, "faults_injected": 0,
                       "faults_detected": 0, "degrade_transitions": 0,
-                      "straggler_events": 0, "heartbeat_trips": 0}
+                      "heartbeat_trips": 0, "decode_rows": 0}
         if self._spec is not None:
             self.stats.update({"spec_rounds": 0, "spec_drafted": 0,
                                "spec_accepted": 0, "spec_committed": 0})
@@ -1313,10 +1393,11 @@ class Engine:
             queue.push(r)
         results: Dict[int, EngineResult] = {}
         t0 = time.monotonic()
-        if self.kv_layout == "paged":
-            self._run_paged(queue, results)
-        else:
-            self._run_dense(queue, results)
+        with self._span("engine.run"):
+            if self.kv_layout == "paged":
+                self._run_paged(queue, results)
+            else:
+                self._run_dense(queue, results)
         self.stats["wall_s"] = time.monotonic() - t0
         self.stats["kv_capacity_bytes"] = self._kv_capacity_bytes
         if self._spec is not None:
@@ -1356,41 +1437,59 @@ class Engine:
         step = 0
         with self._ctx():
             while len(queue) or batch.any_active():
-                # idle + only future arrivals: fast-forward the step clock
-                if not batch.any_active() and queue.ready(step) == 0:
-                    step = max(step, queue.next_arrival())
-                now = time.monotonic()
-                for r in queue:
-                    if r.arrival_step <= step and id(r) not in t_ready:
-                        t_ready[id(r)] = now
-                self._timeout_queued(queue, t_ready, step, now, results)
-                self._timeout_running(batch, step, now, results)
-                admits = self.scheduler.admissions(
-                    queue, batch.free_slots(), batch.n_active, step,
-                    now=now, t_ready=t_ready)
-                for _, req in admits:
-                    self._meta(req)     # pin variant/degraded at admission
-                self._shed_backlog(queue, t_ready, step, now, results)
-                if admits:
-                    for slot in self._admit_dense(batch, admits, step,
-                                                  t_ready):
-                        self._maybe_retire(batch, slot, time.monotonic(),
-                                           step, results)
-                if not batch.any_active():
-                    continue
-                t = time.monotonic()
-                tok, keys, batch.caches = self._decode(
-                    self.params, batch.last_tok, batch.caches,
-                    batch.lengths, batch.active, batch.rng)
-                tok = np.asarray(tok)               # sync
-                act = np.nonzero(batch.active)[0]
-                if self.sampling is not None:
-                    batch.rng[act] = np.asarray(keys)[act]
-                now = time.monotonic()
-                self.stats["decode_s"] += now - t
-                self.stats["decode_steps"] += 1
-                self._postdecode(batch, tok, now, step, results)
-                step += 1
+                with self._span("engine.step"):
+                    with self._span("engine.schedule"):
+                        # idle + only future arrivals: fast-forward the
+                        # step clock
+                        if not batch.any_active() and \
+                                queue.ready(step) == 0:
+                            step = max(step, queue.next_arrival())
+                        now = time.monotonic()
+                        for r in queue:
+                            if r.arrival_step <= step and \
+                                    id(r) not in t_ready:
+                                t_ready[id(r)] = now
+                        self._timeout_queued(queue, t_ready, step, now,
+                                             results)
+                        self._timeout_running(batch, step, now, results)
+                        admits = self.scheduler.admissions(
+                            queue, batch.free_slots(), batch.n_active, step,
+                            now=now, t_ready=t_ready)
+                        for _, req in admits:
+                            self._meta(req)     # pin variant/degraded
+                        self._shed_backlog(queue, t_ready, step, now,
+                                           results)
+                    if admits:
+                        for slot in self._admit_dense(batch, admits, step,
+                                                      t_ready):
+                            self._maybe_retire(batch, slot,
+                                               time.monotonic(), step,
+                                               results)
+                    if not batch.any_active():
+                        continue
+                    with self._span("engine.decode"):
+                        self._dense_decode(batch, step, results)
+                    step += 1
+
+    def _dense_decode(self, batch: BatchState, step: int,
+                      results: Dict[int, "EngineResult"]):
+        """One decode step of every active dense slot."""
+        t = time.monotonic()
+        with self._span("engine.decode.dispatch"):
+            tok, keys, batch.caches = self._decode(
+                self.params, batch.last_tok, batch.caches, batch.lengths,
+                batch.active, batch.rng)
+        self.stats["decode_rows"] += batch.n_active
+        with self._span("engine.decode.wait"):
+            tok = np.asarray(tok)                   # sync
+            act = np.nonzero(batch.active)[0]
+            if self.sampling is not None:
+                batch.rng[act] = np.asarray(keys)[act]
+        now = time.monotonic()
+        self.stats["decode_s"] += now - t
+        self.stats["decode_steps"] += 1
+        with self._span("engine.decode.commit"):
+            self._postdecode(batch, tok, now, step, results)
 
     def _run_paged(self, queue: RequestQueue,
                    results: Dict[int, "EngineResult"]):
@@ -1411,69 +1510,78 @@ class Engine:
             hosts=list(range(self.max_batch)),
             deadline_s=float(self.heartbeat_steps),
             clock=lambda: float(step_ref[0]))
-        straggler = StragglerPolicy()
         with self._ctx():
             while len(queue) or batch.any_busy():
-                if not batch.any_busy() and queue.ready(step) == 0:
-                    step = max(step, queue.next_arrival())
-                step_ref[0] = step
-                now = time.monotonic()
-                for r in queue:
-                    if r.arrival_step <= step and id(r) not in t_ready:
-                        t_ready[id(r)] = now
-                self._timeout_queued(queue, t_ready, step, now, results)
-                self._timeout_running(batch, step, now, results)
-                if self.scheduler.preempts:
-                    self._maybe_preempt(batch, queue, t_ready, step, now)
-                reserved = [0]
+                with self._span("engine.step"):
+                    with self._span("engine.schedule"):
+                        if not batch.any_busy() and queue.ready(step) == 0:
+                            step = max(step, queue.next_arrival())
+                        step_ref[0] = step
+                        now = time.monotonic()
+                        for r in queue:
+                            if r.arrival_step <= step and \
+                                    id(r) not in t_ready:
+                                t_ready[id(r)] = now
+                        self._timeout_queued(queue, t_ready, step, now,
+                                             results)
+                        self._timeout_running(batch, step, now, results)
+                        if self.scheduler.preempts:
+                            self._maybe_preempt(batch, queue, t_ready, step,
+                                                now)
+                        reserved = [0]
 
-                def fits(req):
-                    # running reservation: one admission round may pop
-                    # several requests before any pages are allocated
-                    need = self._pages_needed(req)
-                    if reserved[0] + need <= self.pool_mgr.available():
-                        reserved[0] += need
-                        return True
-                    return False
+                        def fits(req):
+                            # running reservation: one admission round may
+                            # pop several requests before any pages are
+                            # allocated
+                            need = self._pages_needed(req)
+                            if reserved[0] + need <= \
+                                    self.pool_mgr.available():
+                                reserved[0] += need
+                                return True
+                            return False
 
-                admits = self.scheduler.admissions(
-                    queue, self._free_slots(batch, step), batch.n_busy,
-                    step, fits=fits, now=now, t_ready=t_ready)
-                for _, req in admits:
-                    self._meta(req)     # pin variant/degraded at admission
-                if admits:
-                    self._admit_paged(batch, admits, step, t_ready)
-                self._shed_backlog(queue, t_ready, step, now, results,
-                                   free_frac=(self.pool_mgr.available()
-                                              / self.num_pages))
-                self._apply_faults(batch, step)
-                if batch.prefilling.any():
-                    self._chunk_step(batch, step, results, queue=queue,
-                                     t_ready=t_ready)
-                if batch.any_active():
-                    t_step = time.monotonic()
-                    if self._spec is not None:
-                        self._spec_round(batch, step, results)
-                    else:
-                        self._decode_groups(batch, step, results,
-                                            queue=queue, t_ready=t_ready)
-                    if straggler.observe(step, time.monotonic() - t_step) \
-                            != "ok":
-                        self.stats["straggler_events"] += 1
-                # idle slots are not stuck: keep their heartbeats fresh
-                for b in range(self.max_batch):
-                    if not (batch.active[b] or batch.prefilling[b]):
+                        admits = self.scheduler.admissions(
+                            queue, self._free_slots(batch, step),
+                            batch.n_busy, step, fits=fits, now=now,
+                            t_ready=t_ready)
+                        for _, req in admits:
+                            self._meta(req)     # pin variant/degraded
+                    if admits:
+                        with self._span("engine.admit"):
+                            self._admit_paged(batch, admits, step, t_ready)
+                    with self._span("engine.schedule"):
+                        self._shed_backlog(
+                            queue, t_ready, step, now, results,
+                            free_frac=(self.pool_mgr.available()
+                                       / self.num_pages))
+                        self._apply_faults(batch, step)
+                    if batch.prefilling.any():
+                        with self._span("engine.chunk"):
+                            self._chunk_step(batch, step, results,
+                                             queue=queue, t_ready=t_ready)
+                    if batch.any_active():
+                        with self._span("engine.decode"):
+                            if self._spec is not None:
+                                self._spec_round(batch, step, results)
+                            else:
+                                self._decode_groups(batch, step, results,
+                                                    queue=queue,
+                                                    t_ready=t_ready)
+                    # idle slots are not stuck: keep their heartbeats fresh
+                    for b in range(self.max_batch):
+                        if not (batch.active[b] or batch.prefilling[b]):
+                            self._monitor.beat(b)
+                    for b in self._monitor.dead_hosts():
+                        if batch.active[b] or batch.prefilling[b]:
+                            self.stats["heartbeat_trips"] += 1
+                            self._handle_fault(batch, queue, int(b), step,
+                                               time.monotonic(), t_ready,
+                                               results, purge=False,
+                                               kind="stuck")
                         self._monitor.beat(b)
-                for b in self._monitor.dead_hosts():
-                    if batch.active[b] or batch.prefilling[b]:
-                        self.stats["heartbeat_trips"] += 1
-                        self._handle_fault(batch, queue, int(b), step,
-                                           time.monotonic(), t_ready,
-                                           results, purge=False,
-                                           kind="stuck")
-                    self._monitor.beat(b)
-                if self._degrade is not None:
-                    self._degrade.update(step)   # _meta reads .active
+                    if self._degrade is not None:
+                        self._degrade.update(step)   # _meta reads .active
                 step += 1
         self._monitor = None
         self._paged_caches = batch.caches       # keep cached pages resident

@@ -33,6 +33,8 @@ class RequestResult:
     degraded: bool = False            # served by the degrade_to variant
     preemptions: int = 0              # retire-and-requeue round-trips
     requeues: int = 0                 # fault-recovery requeues
+    queue_s: float = 0.0              # became-schedulable -> first admission
+                                      # into a slot (part of ttft_s)
 
     @property
     def n_tokens(self) -> int:

@@ -191,6 +191,7 @@ def run_phases(arch: str, *, reduce: bool, seed: int, out_dir, requests=8,
     oracle_prefill = compile_prefill(oracle)
     times["compile"] = time.perf_counter() - t
     _expect_kernel("served-model prefill", kernel_prefill, on_tpu, log)
+    log(f"quant_matmul blocks by layer and rows: {kernel.kernel_blocks()}")
     got = kernel_prefill(params, tokens, caches, lengths)
     want = oracle_prefill(params, tokens, caches, lengths)
     logits_diff = _compare(f"first-step logits of {len(reqs)} served prompts",

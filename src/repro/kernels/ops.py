@@ -6,6 +6,7 @@ kernel.
 from __future__ import annotations
 
 from functools import partial
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +14,7 @@ import numpy as np
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.quant_matmul import quant_matmul
+from repro.kernels.quant_matmul import quant_matmul, vmem_bytes
 from repro.kernels.split_precision import split_precision_matmul
 from repro.kernels.split_ternary import split_ternary_matmul
 from repro.kernels.ternary_matmul import ternary_matmul
@@ -42,19 +43,51 @@ def _pad_to(x, mult, axis):
     return jnp.pad(x, widths)
 
 
+#: largest row and column blocks `quant_matmul_blocks` takes; a full
+#: 1024x1024 tile does 1024 int8 operations per HBM byte it reads, over
+#: v5e's ridge of 393e12 / 819e9 = 480
+QUANT_BM, QUANT_BN = 1024, 1024
+#: VMEM the chosen tiles may fill (v5e has 128 MiB)
+QUANT_VMEM_BUDGET = 64 * 2**20
+
+
+def quant_matmul_blocks(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    """(bm, bn, bk) for a w8a8 matmul of shape (m, k) x (k, n).
+
+    The whole of K in one block, so each output tile is summed in one grid
+    step; up to `QUANT_BM` rows, so the weight streams from HBM once
+    per row block (once per call for a decode batch), and up to `QUANT_BN`
+    columns.  A block that covers its whole axis takes the axis's length,
+    which Mosaic accepts whatever its tiling.  While the tiles overfill
+    `QUANT_VMEM_BUDGET`, K, then N, then M blocks halve."""
+    bk = -(-k // 128) * 128
+    bm, bn = min(m, QUANT_BM), min(n, QUANT_BN)
+    while vmem_bytes(bm, bn, bk) > QUANT_VMEM_BUDGET:
+        if bk > 512 and bk % 256 == 0:
+            bk //= 2
+        elif bn > 256:
+            bn = bn // 2 // 128 * 128
+        elif bm > 256:
+            bm = bm // 2 // 32 * 32
+        else:
+            break
+    return bm, bn, bk
+
+
 @partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def quant_matmul_op(x_q, w_q, sx, sw, bm=128, bn=128, bk=512,
+def quant_matmul_op(x_q, w_q, sx, sw, bm=None, bn=None, bk=None,
                     interpret=None):
-    """Shape-flexible w8a8 matmul (pads to block multiples, then slices)."""
+    """Shape-flexible w8a8 matmul.  Blocks left as None come from
+    `quant_matmul_blocks`; K zero-pads to a multiple of ``bk``, M and N
+    end in ragged blocks, so no operand is copied but a ragged K."""
     interpret = _on_cpu() if interpret is None else interpret
-    m, n = x_q.shape[0], w_q.shape[1]
-    bm_, bn_, bk_ = (min(bm, max(8, m)), min(bn, max(128, n)), bk)
-    xq = _pad_to(_pad_to(x_q, bm_, 0), bk_, 1)
-    wq = _pad_to(_pad_to(w_q, bk_, 0), bn_, 1)
-    swp = _pad_to(sw, bn_, 0)
-    out = quant_matmul(xq, wq, sx, swp, bm=bm_, bn=bn_, bk=bk_,
-                       interpret=interpret)
-    return out[:m, :n]
+    (m, k), n = x_q.shape, w_q.shape[1]
+    if None in (bm, bn, bk):
+        cbm, cbn, cbk = quant_matmul_blocks(m, k, n)
+        bm, bn, bk = bm or cbm, bn or cbn, bk or cbk
+    return quant_matmul(_pad_to(x_q, bk, 1), _pad_to(w_q, bk, 0), sx, sw,
+                        bm=min(bm, m), bn=min(bn, n), bk=bk,
+                        interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))

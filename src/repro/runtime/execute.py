@@ -9,12 +9,15 @@ lowered without scales), the bf16 weight cast of the split kernel, the
 2-bit-packed ternary stream of the split_ternary kernel, the static
 activation-quant scale/step, the block-aligned split boundary, and the
 resolved kernel block sizes (``LayerPlan.tuning`` overrides threaded down
-to the Pallas calls).  `execute_layer` itself only quantizes the
-activations and calls the kernel — nothing about the weights is rebuilt
-per call.  Both 2-D dense weights and 4-D HWIO conv weights bind — conv
-weights are flattened to ``(kh*kw*c_in, c_out)`` and executed through
-`execute_conv_layer`, which im2cols the NHWC input so CNN artifacts run
-through the same fused Pallas kernels as dense layers.
+to the Pallas calls; an untuned ``quant_matmul`` layer takes
+`kernels.ops.quant_matmul_blocks` of each call's shape instead, and
+records the blocks per row count in ``PreparedLayer.chosen``).
+`execute_layer` itself only quantizes the activations and calls the
+kernel — nothing about the weights is rebuilt per call.  Both 2-D dense
+weights and 4-D HWIO conv weights bind — conv weights are flattened to
+``(kh*kw*c_in, c_out)`` and executed through `execute_conv_layer`, which
+im2cols the NHWC input so CNN artifacts run through the same fused Pallas
+kernels as dense layers.
 
 `execute_layer` runs an input through the matching Pallas kernel —
 interpret mode on CPU — or through the pure-jnp reference oracle
@@ -96,7 +99,11 @@ class PreparedLayer:
     act_scale: jax.Array | None = None   # exp(act_log_scale), f32 scalar
     act_sx: jax.Array | None = None      # act dequant step, f32 scalar
     boundary: int = 0                # raw split boundary (static)
-    blocks: Tuple[int, int, int] = (DEFAULT_BM, 128, DEFAULT_BK)  # bm,bn,bk
+    # bm, bn, bk; None: chosen per call from its shape (untuned quant)
+    blocks: Tuple[int, int, int] | None = (DEFAULT_BM, 128, DEFAULT_BK)
+    # rows -> (bm, bn, bk) of each traced quant_matmul call
+    chosen: Dict[int, Tuple[int, int, int]] = dataclasses.field(
+        default_factory=dict)
 
     @property
     def kernel(self) -> str:
@@ -152,9 +159,14 @@ def _per_column_quant(lp: LayerPlan, wf: jax.Array,
     return w_q, sw
 
 
-def _resolve_blocks(lp: LayerPlan, block_n: int) -> Tuple[int, int, int]:
+def _resolve_blocks(lp: LayerPlan,
+                    block_n: int) -> Tuple[int, int, int] | None:
     """(bm, bn, bk) for the layer's kernel calls: plan-level ``block_n``
-    with `LayerPlan.tuning` overrides."""
+    with `LayerPlan.tuning` overrides.  None for an untuned ``quant_matmul``
+    layer, whose calls take blocks from their shapes: its ``bn`` bounds no
+    domain, where the split kernels' aligns the boundary (numerics)."""
+    if lp.kernel == KERNEL_QUANT and not lp.tuning:
+        return None
     tun = lp.tuning or {}
     bm = int(tun.get("bm", DEFAULT_BM))
     bn = int(tun.get("bn", block_n))
@@ -285,7 +297,8 @@ def execute_layer(prep: PreparedLayer, x, *, interpret=None,
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     xf = x2.astype(jnp.float32)
-    bm, bn, bk = prep.blocks
+    m, k = x2.shape
+    bm, bn, bk = prep.blocks or ops.quant_matmul_blocks(m, k, lp.c_out)
     # the ops clamp the N-block to min(bn, max(128, n)) and round the
     # boundary up to it; the oracles must split at the same column
     bn_eff = min(bn, max(128, lp.c_out))
@@ -301,6 +314,8 @@ def execute_layer(prep: PreparedLayer, x, *, interpret=None,
         else:
             fn = (ops.ternary_matmul_op if lp.kernel == KERNEL_TERNARY
                   else ops.quant_matmul_op)
+            if lp.kernel == KERNEL_QUANT:
+                prep.chosen[m] = (bm, bn, bk)
             y = fn(x_q, prep.w_q, sx, prep.sw, bm=bm, bn=bn, bk=bk,
                    interpret=interpret)
     elif lp.kernel == KERNEL_SPLIT_TERNARY:
@@ -433,6 +448,7 @@ class _StackedPrepared:
         self.conv_shape = p0.conv_shape
         self.conv_groups = p0.plan.groups
         self.boundary, self.blocks = p0.boundary, p0.blocks
+        self.chosen = p0.chosen
         self.n_repeats = len(preps)
         st = lambda get: (None if get(p0) is None
                           else jnp.stack([jnp.asarray(get(p)) for p in preps]))
@@ -456,7 +472,7 @@ class _StackedPrepared:
             block_n=self.block_n, conv_shape=self.conv_shape,
             w_bf16=take(self._w_bf16), w_t_packed=take(self._w_t_packed),
             act_scale=take(self._act_scale), act_sx=take(self._act_sx),
-            boundary=self.boundary, blocks=self.blocks)
+            boundary=self.boundary, blocks=self.blocks, chosen=self.chosen)
 
     def execute(self, x, r, conv=None, *, interpret=None, reference=False):
         prep = self.at(r)
@@ -576,6 +592,19 @@ class _SwitchPrepared:
 
 _STACKED_TYPES = (_SingleRepeat, _StackedPrepared, _GroupedPrepared,
                   _SwitchPrepared)
+
+
+def _members(entry):
+    """The `PreparedLayer`s (or homogeneous stacks) a bound entry runs."""
+    if isinstance(entry, _SingleRepeat):
+        yield entry.prep
+    elif isinstance(entry, _GroupedPrepared):
+        for g in entry.groups:
+            yield from _members(g)
+    elif isinstance(entry, _SwitchPrepared):
+        yield from entry.preps
+    else:
+        yield entry
 
 
 # --------------------------------------------------------------------------
@@ -902,6 +931,19 @@ class PlanSet:
         names, not counts."""
         return {v: list(bp.unbound) for v, bp in self._variants.items()
                 if bp.unbound}
+
+    def kernel_blocks(self) -> Dict[str, Dict[int, Tuple[int, int, int]]]:
+        """Layer name -> {rows: (bm, bn, bk)} of every ``quant_matmul``
+        call traced so far, per row count; a stacked layer reads under the
+        name of its first repeat in each group of repeats."""
+        out: Dict[str, Dict[int, Tuple[int, int, int]]] = {}
+        for bp in self._variants.values():
+            for entry in bp.by_name.values():
+                for member in _members(entry):
+                    if member.chosen:
+                        out.setdefault(member.plan.name, {}).update(
+                            member.chosen)
+        return out
 
     # ---- memory accounting ----------------------------------------------
 

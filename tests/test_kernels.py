@@ -19,22 +19,92 @@ def _rand_int8(key, shape, lo=-127, hi=128):
 
 
 # ------------------------------------------------------------ quant_matmul
+def _quant_operands(m, k, n, seed=None):
+    key = jax.random.PRNGKey(m + k + n if seed is None else seed)
+    xq = _rand_int8(key, (m, k))
+    wq = _rand_int8(jax.random.fold_in(key, 1), (k, n))
+    sx = jnp.asarray(0.013, jnp.float32)
+    sw = jax.random.uniform(jax.random.fold_in(key, 2), (n,), jnp.float32)
+    return xq, wq, sx, sw
+
+
 @pytest.mark.parametrize("m,k,n,bm,bn,bk", [
     (128, 512, 128, 128, 128, 512),
     (256, 1024, 256, 128, 128, 512),
     (8, 512, 128, 8, 128, 512),
     (128, 512, 384, 128, 128, 256),
+    (64, 1024, 340, 64, 256, 1024),   # ragged last N block, full-K block
+    (96, 512, 300, 32, 128, 512),     # ragged N, bm != bn
+    (100, 256, 256, 32, 256, 128),    # ragged last M block, K in 2 blocks
 ])
 def test_quant_matmul_blocks(m, k, n, bm, bn, bk):
-    key = jax.random.PRNGKey(m + k + n)
-    xq = _rand_int8(key, (m, k))
-    wq = _rand_int8(jax.random.fold_in(key, 1), (k, n))
-    sx = jnp.asarray(0.013, jnp.float32)
-    sw = jax.random.uniform(jax.random.fold_in(key, 2), (n,), jnp.float32)
+    """Accumulation is exact in int32 and the scales apply per element at
+    the flush, so every tiling gives the oracle's bits."""
+    xq, wq, sx, sw = _quant_operands(m, k, n)
     out = quant_matmul(xq, wq, sx, sw, bm=bm, bn=bn, bk=bk, interpret=True)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(ref.quant_matmul_ref(xq, wq, sx, sw)),
-                               rtol=1e-6)
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(ref.quant_matmul_ref(xq, wq, sx, sw)))
+
+
+@pytest.mark.parametrize("m,k,n,blocks", [
+    (48, 256, 1100, None),            # chosen: (48, 1024, 256), ragged N
+    (1100, 256, 1100, None),          # chosen: (1024, 1024, 256), ragged M, N
+    (16, 200, 300, None),             # chosen: K zero-pads to 256
+    (16, 2048, 8512, (128, 128, 512)),  # the fixed tiling it replaced
+    (40, 640, 340, (32, 256, 640)),   # explicit: bm != bn, full K, ragged
+])
+def test_quant_matmul_op_bit_equal(m, k, n, blocks):
+    xq, wq, sx, sw = _quant_operands(m, k, n)
+    kw = {} if blocks is None else dict(zip(("bm", "bn", "bk"), blocks))
+    out = ops.quant_matmul_op(xq, wq, sx, sw, interpret=True, **kw)
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(ref.quant_matmul_ref(xq, wq, sx, sw)))
+
+
+#: v5e: 393e12 int8 operations/s over 819e9 HBM bytes/s
+INT8_RIDGE = 393e12 / 819e9
+
+
+@pytest.mark.parametrize("m", [16, 4096])
+@pytest.mark.parametrize("k,n", [(2048, 8512), (4096, 2048)])
+def test_quant_matmul_blocks_chosen(m, k, n):
+    """The chooser at the mamba2-1.3b chat cell's projections (decode
+    M=16, chunk M=4096; in_proj and out_proj)."""
+    from repro.kernels.quant_matmul import vmem_bytes, vmem_limit_bytes
+    bm, bn, bk = ops.quant_matmul_blocks(m, k, n)
+    assert bk == k                      # one K step per output tile
+    assert vmem_bytes(bm, bn, bk) <= ops.QUANT_VMEM_BUDGET
+    assert vmem_bytes(bm, bn, bk) < vmem_limit_bytes(bm, bn, bk)
+    if m <= 32:                         # decode: one row block, wide columns
+        assert bm == m and bn >= 1024
+        assert -(-n // bn) <= 9         # a few grid steps stream w once
+    else:                               # chunk: tiles over the ridge
+        assert 2 * bm * bn / (bm + bn) >= INT8_RIDGE
+        assert bm % 256 == 0 or 256 % bm == 0  # whole prefill slots
+
+
+@pytest.mark.parametrize("tuning", [None, {"bm": 8, "bn": 128, "bk": 64}])
+def test_quant_layer_blocks_tuning_wins(tuning):
+    """An explicit `LayerPlan.tuning` reaches the kernel; an untuned
+    quant_matmul layer takes the chooser's blocks per call shape.  Either
+    way the layer gives the oracle's bits."""
+    from repro.runtime import KERNEL_QUANT, LayerPlan, execute_layer, \
+        prepare_layer
+    m, k, n = 24, 128, 300
+    rng = np.random.default_rng(5)
+    lp = LayerPlan(name="proj", kernel=KERNEL_QUANT, c_in=k, c_out=n,
+                   perm=np.arange(n), counts=[n], boundaries=[n],
+                   aligned_boundaries=[n], w_log_scales=[0.0],
+                   act_log_scale=1.0, tuning=tuning)
+    prep = prepare_layer(lp, jnp.asarray(rng.normal(size=(k, n)) * 0.1,
+                                         jnp.float32))
+    x = jnp.asarray(rng.normal(size=(2, m // 2, k)), jnp.float32)
+    y = execute_layer(prep, x, interpret=True)
+    want = (8, 128, 64) if tuning else ops.quant_matmul_blocks(m, k, n)
+    assert prep.blocks == (want if tuning else None)
+    assert prep.chosen == {m: want}
+    np.testing.assert_array_equal(
+        np.asarray(y), np.asarray(execute_layer(prep, x, reference=True)))
 
 
 @settings(max_examples=8, deadline=None)

@@ -1,7 +1,9 @@
 """Compile the planned matmul kernels for a described TPU v5e, no chip
 attached: at zamba2-1.2b projection widths (K=2048, N=8192) for a decode
 batch (M=4) and a prefill chunk (M=128), each op must lower to a compiled
-Mosaic kernel (``tpu_custom_call``), not to interpret mode.
+Mosaic kernel (``tpu_custom_call``), not to interpret mode.  So must
+``quant_matmul`` at the mamba2-1.3b chat cell's own projections, under the
+blocks `ops.quant_matmul_blocks` chooses for them.
 
 Interpret-mode parity tests cannot catch what only the TPU compiler
 refuses (memory spaces, int8 vector arithmetic, tiling); these can.  The
@@ -68,4 +70,16 @@ def test_op_compiles_to_a_mosaic_kernel_for_v5e(one_chip, op, m):
     # CPU here; the chip resolves it to False, so compile that explicitly
     compiled = jax.jit(lambda *a: fn(*a, interpret=False, **kw)).lower(
         *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("m", [16, 4096])
+@pytest.mark.parametrize("k,n", [(2048, 8512), (4096, 2048)])
+def test_quant_matmul_compiles_at_chosen_blocks(one_chip, m, k, n):
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    args = (sds((m, k), jnp.int8), sds((k, n), jnp.int8),
+            sds((), jnp.float32), sds((n,), jnp.float32))
+    compiled = jax.jit(lambda *a: ops.quant_matmul_op(
+        *a, interpret=False)).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()

@@ -105,20 +105,34 @@ def program_cfg(config: dict):
     return dataclasses.replace(base, **upd)
 
 
-def check_shapes(shapes, sizes: dict):
-    """The weights the program asks for have the file's widths."""
-    d, V = sizes["d_model"], sizes["vocab"]
-    if tuple(shapes["emb"].shape) != (V, d):
-        raise BenchError(f"emb {shapes['emb'].shape} is not ({V}, {d})")
-    if "mamba" in sizes["pattern"]:
-        di, N = sizes["mamba_expand"] * d, sizes["ssm_state"]
-        H = di // sizes["mamba_headdim"]
-        i = list(sizes["pattern"]).index("mamba")
-        m = shapes["units"][i]["mamba"]
-        want = (2 * di + 2 * N + H, (sizes["mamba_d_conv"], di + 2 * N))
-        got = (m["in_proj"]["w"].shape[-1], tuple(m["conv_w"].shape[-2:]))
-        if got != want:
-            raise BenchError(f"mamba widths {got} are not {want}")
+def _is_shape(x) -> bool:
+    return isinstance(x, tuple) and all(isinstance(i, int) for i in x)
+
+
+def _leaf_paths(tree, is_leaf=None) -> dict:
+    import jax
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree, is_leaf=is_leaf)
+    return {jax.tree_util.keystr(p, simple=True, separator="/"): leaf
+            for p, leaf in flat}
+
+
+def check_shapes(shapes, sizes: dict, reference):
+    """The weights the program asks for are those the reference reads, at
+    its widths: every leaf path and shape of ``shapes`` (the program's
+    tree, from `jax.eval_shape`) against ``reference.param_shapes(sizes)``."""
+    if not hasattr(reference, "param_shapes"):
+        raise BenchError(f"reference {reference.__name__} has no "
+                         f"param_shapes(sizes) to declare its widths")
+    got = {p: tuple(s.shape) for p, s in _leaf_paths(shapes).items()}
+    want = _leaf_paths(reference.param_shapes(sizes), _is_shape)
+    bad = [p for p in sorted(got.keys() | want.keys())
+           if got.get(p) != want.get(p)]
+    if bad:
+        raise BenchError(
+            f"program weights unlike the reference's ({len(bad)}): " +
+            "; ".join(
+                f"{p} wants {want.get(p, 'no leaf')}, got "
+                f"{got.get(p, 'no leaf')}" for p in bad[:8]))
 
 
 def _requests(draws):
@@ -172,7 +186,8 @@ def run_cell(bench: dict, name: str, *, seed: int, seconds: float,
     t = time.perf_counter()
     cfg = program_cfg(config)
     shapes = jax.eval_shape(lambda: T.init_lm(jax.random.PRNGKey(0), cfg))
-    check_shapes(shapes, sizes)
+    reference = check_mod.load_reference(root / config["reference"])
+    check_shapes(shapes, sizes, reference)
     params = jax.block_until_ready(make_params(shapes, seed))
     phase("weights", t)
 
@@ -268,7 +283,6 @@ def run_cell(bench: dict, name: str, *, seed: int, seconds: float,
     gc.collect()
     jax.clear_caches()
     t = time.perf_counter()
-    reference = check_mod.load_reference(root / config["reference"])
     gap_rows = check_mod.gaps(reference, params, sizes, picked, prompts)
     widest = float(max(g.max() for g in gap_rows)) if gap_rows else \
         float("inf")

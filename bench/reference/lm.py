@@ -25,6 +25,10 @@ step, the skip ``D``, a SiLU(z) gate, an RMSNorm and out_proj.  RMSNorm has
 eps 1e-5.  Logits are RMSNorm(x) Whead, or RMSNorm(x) Wemb^T where the
 embeddings are tied (no ``head`` leaf).
 
+`param_shapes` declares the shape of every weight it reads, for the
+harness's check at set-up.  It computes Mamba2 with one group only, and
+refuses ``mamba_ngroups`` other than 1.
+
 ``bits`` turns the reference into the precision control: every projection's
 weight is rounded to that many bits on one max-abs scale per matrix, and its
 input to as many bits on the static scale exp(act_log_scale).
@@ -179,6 +183,58 @@ def logits(params, sizes: dict, tokens, rows, *, bits=None,
     head = params["head"]["w"] if "head" in params else params["emb"].T
     return _head(params["final_norm"]["scale"], head, x,
                  jnp.asarray(rows), q=q)
+
+
+def _block_shapes(kind: str, sizes: dict, lead: tuple = ()) -> dict:
+    """Shapes of the weights one block of ``kind`` reads, each with the
+    leading axes ``lead`` (the repeat axis of a scan-stacked block)."""
+    d = sizes["d_model"]
+    norm = lambda n: {"scale": (*lead, n)}
+    dense = lambda i, o: {"w": (*lead, i, o)}
+    if kind == "mamba":
+        di = sizes["mamba_expand"] * d
+        N, K = sizes["ssm_state"], sizes["mamba_d_conv"]
+        H = di // sizes["mamba_headdim"]
+        return {"norm1": norm(d), "mamba": {
+            "in_proj": dense(d, 2 * di + 2 * N + H),
+            "conv_w": (*lead, K, di + 2 * N), "conv_b": (*lead, di + 2 * N),
+            "dt_bias": (*lead, H), "A_log": (*lead, H), "D": (*lead, H),
+            "norm": norm(di), "out_proj": dense(di, d)}}
+    if kind == "shared_attn":
+        return {"norm1": norm(d)}
+    if kind == "attn":
+        H, KVH, hd = sizes["n_heads"], sizes["n_kv_heads"], sizes["head_dim"]
+        f = sizes["d_ff"]
+        return {"norm1": norm(d), "norm2": norm(d),
+                "attn": {"wq": dense(d, H * hd), "wk": dense(d, KVH * hd),
+                         "wv": dense(d, KVH * hd), "wo": dense(H * hd, d)},
+                "ffn": {"gate": dense(d, f), "up": dense(d, f),
+                        "down": dense(f, d)}}
+    raise ValueError(f"block kind {kind!r}: this reference computes "
+                     f"mamba, attn and shared_attn")
+
+
+def param_shapes(sizes: dict) -> dict:
+    """Shapes of every weight `logits` reads, in the program's tree: the
+    ``pattern``'s blocks under ``units``, each stacked over the pattern's
+    repeats, the remainder's under ``rem``, the shared block's attention
+    and FFN under ``shared``.  Leaves are tuples of ints."""
+    if sizes.get("mamba_ngroups", 1) != 1:
+        raise ValueError(f"mamba_ngroups {sizes['mamba_ngroups']}: this "
+                         f"reference computes Mamba2 with one group")
+    d, V = sizes["d_model"], sizes["vocab"]
+    pattern = list(sizes["pattern"])
+    reps, rem = divmod(sizes["n_layers"], len(pattern))
+    out = {"emb": (V, d), "final_norm": {"scale": (d,)},
+           "units": tuple(_block_shapes(k, sizes, (reps,)) for k in pattern)}
+    if not sizes["tie_embeddings"]:
+        out["head"] = {"w": (d, V)}
+    if rem:
+        out["rem"] = [_block_shapes(k, sizes) for k in pattern[:rem]]
+    if "shared_attn" in pattern:
+        blk = _block_shapes("attn", sizes)
+        out["shared"] = {k: blk[k] for k in ("attn", "ffn", "norm2")}
+    return out
 
 
 def _kinds(sizes: dict):

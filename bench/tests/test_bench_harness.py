@@ -1,10 +1,12 @@
 """The harness end to end on the CPU at a small size, its refusal of the
-CPU, its correctness check against planted faults, and BENCHMARK.json
+CPU, its set-up check of the program's weights against the reference's
+widths, its correctness check against planted faults, and BENCHMARK.json
 against the benchmark contract."""
 import json
 import re
 import shutil
 import sys
+import types
 from pathlib import Path
 
 import numpy as np
@@ -133,6 +135,99 @@ def test_main_refuses_the_cpu(capsys):
     assert harness.main(["--workload", "mamba2-1.3b.chat", "--seed", "1",
                          "--seconds", "1"]) != 0
     assert capsys.readouterr().out == ""
+
+
+def program_tree(name, **program_sizes):
+    """The configuration's sizes and the program's tree of weight shapes,
+    built with ``program_sizes`` in place of the file's."""
+    import jax
+    from repro.models import transformer as T
+    path = DATA / f"{name}.json"
+    config = json.loads((path if path.exists() else
+                         BENCH / "configs" / f"{name}.json").read_text())
+    sizes = config["sizes"]
+    config["sizes"] = {**sizes, **program_sizes}
+    cfg = harness.program_cfg(config)
+    return sizes, jax.eval_shape(
+        lambda: T.init_lm(jax.random.PRNGKey(0), cfg))
+
+
+def reference(path=BENCH / "reference" / "lm.py"):
+    return harness.check_mod.load_reference(path)
+
+
+@pytest.mark.parametrize("name", ["zamba2-smoke", "yi-smoke", "mamba2-smoke",
+                                  "mamba2-1.3b"])
+def test_reference_declares_the_program_tree(name):
+    sizes, tree = program_tree(name)
+    ref = reference()
+    want = harness._leaf_paths(ref.param_shapes(sizes), harness._is_shape)
+    assert {p: tuple(s.shape) for p, s in
+            harness._leaf_paths(tree).items()} == want
+    harness.check_shapes(tree, sizes, ref)
+
+
+def _set(tree, path, shape):
+    """Give the leaf at ``path`` the shape ``shape``; None removes it."""
+    import jax
+    *up, leaf = path.split("/")
+    for k in up:
+        tree = tree[int(k) if k.isdigit() else k]
+    if shape is None:
+        del tree[leaf]
+    else:
+        tree[leaf] = jax.ShapeDtypeStruct(shape, "float32")
+
+
+@pytest.mark.parametrize("name,program_sizes,edit,leaf", [
+    ("mamba2-smoke", {}, ("units/0/mamba/in_proj/w", (2, 64, 289)),
+     "units/0/mamba/in_proj/w"),
+    ("mamba2-smoke", {}, ("units/0/mamba/conv_w", (2, 4, 168)),
+     "units/0/mamba/conv_w"),
+    ("mamba2-smoke", {"vocab": 120}, None, "emb"),
+    ("yi-smoke", {"head_dim": 8}, None, "units/0/attn/wq/w"),
+    ("zamba2-smoke", {"d_ff": 96}, None, "shared/ffn/up/w"),
+    ("zamba2-smoke", {}, ("shared/attn/wq/b", (64,)), "shared/attn/wq/b"),
+    ("yi-smoke", {}, ("units/0/norm2/scale", None), "units/0/norm2/scale"),
+], ids=["in_proj_short", "conv_w_width", "emb_vocab", "wq_head_dim",
+        "ffn_width", "extra_leaf", "missing_leaf"])
+def test_width_departure_is_refused(name, program_sizes, edit, leaf):
+    sizes, tree = program_tree(name, **program_sizes)
+    if edit is not None:
+        _set(tree, *edit)
+    with pytest.raises(harness.BenchError, match=f"{leaf} wants"):
+        harness.check_shapes(tree, sizes, reference())
+
+
+def test_grouped_mamba2_reference_is_accepted():
+    sizes, tree = program_tree("mamba2-smoke")
+    # d 64, di 128, N 16, H 2, two groups: in_proj 2di + 2GN + H, conv di + 2GN
+    for path, shape in (("units/0/mamba/in_proj/w", (2, 64, 322)),
+                        ("units/0/mamba/conv_w", (2, 4, 192)),
+                        ("units/0/mamba/conv_b", (2, 192))):
+        _set(tree, path, shape)
+    harness.check_shapes(tree, {**sizes, "mamba_ngroups": 2},
+                         reference(DATA / "grouped_mamba2.py"))
+    with pytest.raises(harness.BenchError, match="in_proj/w wants"):
+        harness.check_shapes(tree, sizes, reference())
+
+
+def test_lm_reference_refuses_mamba2_groups():
+    sizes, _ = program_tree("mamba2-smoke")
+    with pytest.raises(ValueError, match="mamba_ngroups 2"):
+        reference().param_shapes({**sizes, "mamba_ngroups": 2})
+
+
+def test_reference_without_param_shapes_is_refused(root, monkeypatch):
+    bare = types.ModuleType("bare_reference")
+    monkeypatch.setattr(harness.check_mod, "load_reference",
+                        lambda path: bare)
+
+    def make_params(*a, **k):
+        raise AssertionError("weights made before the width check")
+    monkeypatch.setattr(harness, "make_params", make_params)
+    with pytest.raises(harness.BenchError, match="param_shapes"):
+        run(root, cell=MAMBA_CELL)
 
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")

@@ -8,6 +8,10 @@ Block kinds used in ``pattern`` (the repeating layer unit):
   mamba       Mamba2 block (no FFN)
   mlstm/slstm xLSTM blocks (no FFN)
   shared_attn zamba2 shared transformer block (weights shared across uses)
+  hybrid      Zamba2-7B hybrid layer: one of ``mem_blocks`` shared attention
+              + FFN blocks (used in turn) over concat(x, embeddings), a
+              per-use LoRA on its FFN and a per-use output projection,
+              added to a Mamba2 layer's input
 """
 from __future__ import annotations
 
@@ -50,6 +54,10 @@ class ArchConfig:
     dense_ff: int = 0                # hidden of dense residual / first-dense FFN
     mla: Optional[MLASpec] = None
     ssm_state: int = 64
+    mamba_ngroups: int = 1           # Mamba2 groups of B and C
+    mem_blocks: int = 1              # hybrid: shared blocks, used in turn
+    adapter_rank: int = 0            # hybrid: rank of the per-use FFN LoRA
+    attn_scale: Optional[float] = None  # softmax scale (None: hd ** -0.5)
     encoder_layers: int = 0          # seamless enc-dec
     frontend: Optional[str] = None   # "vision"|"audio" — STUB embeddings
     frontend_tokens: int = 0
@@ -95,16 +103,22 @@ def load_all():
     from repro.configs import (  # noqa: F401
         command_r_35b, nemotron_4_15b, yi_9b, h2o_danube_3_4b,
         llama_3_2_vision_11b, seamless_m4t_large_v2, xlstm_1_3b,
-        arctic_480b, deepseek_v2_lite_16b, zamba2_1_2b,
+        arctic_480b, deepseek_v2_lite_16b, zamba2_1_2b, zamba2_7b,
     )
 
 
 def reduce_for_smoke(cfg: ArchConfig) -> ArchConfig:
     """Shrink a config to CPU-smoke-test size, preserving the family shape."""
-    period = len(cfg.pattern)
+    pattern = cfg.pattern
+    if "hybrid" in pattern:
+        # every shared block used twice, each use after a Mamba2 layer
+        pattern = ("mamba", "hybrid") * (2 * cfg.mem_blocks)
+    period = len(pattern)
     n_layers = period * min(2, max(1, cfg.n_layers // period))
     if cfg.name.startswith("zamba"):
         n_layers = period + 2  # one full period + the remainder mambas
+    if "hybrid" in pattern:
+        n_layers = period
     moe = None
     if cfg.moe is not None:
         moe = dataclasses.replace(cfg.moe, n_experts=8,
@@ -112,10 +126,14 @@ def reduce_for_smoke(cfg: ArchConfig) -> ArchConfig:
     mla = MLASpec(kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
                   v_head_dim=16) if cfg.mla else None
     return dataclasses.replace(
-        cfg, n_layers=n_layers, d_model=64,
+        cfg, n_layers=n_layers, pattern=pattern, d_model=64,
         n_heads=4, n_kv_heads=min(4, max(1, cfg.n_kv_heads)), head_dim=16,
         d_ff=128 if cfg.d_ff else 0, vocab=128, moe=moe, mla=mla,
         dense_ff=96 if cfg.dense_ff else 0,
         encoder_layers=min(2, cfg.encoder_layers),
         frontend_tokens=8 if cfg.frontend_tokens else 0,
-        sliding_window=16 if cfg.sliding_window else None)
+        sliding_window=16 if cfg.sliding_window else None,
+        adapter_rank=8 if cfg.adapter_rank else 0,
+        # the scale keeps its ratio to head_dim ** -0.5
+        attn_scale=(cfg.attn_scale * (cfg.hd / 16) ** 0.5
+                    if cfg.attn_scale else None))

@@ -79,9 +79,10 @@ def quant_matmul_op(x_q, w_q, sx, sw, bm=None, bn=None, bk=None,
                     interpret=None):
     """Shape-flexible w8a8 matmul.  Blocks left as None come from
     `quant_matmul_blocks`; K zero-pads to a multiple of ``bk``, M and N
-    end in ragged blocks, so no operand is copied but a ragged K."""
+    end in ragged blocks, so no operand is copied but a ragged K.  N is
+    ``sw``'s length; ``w_q`` may carry columns past it."""
     interpret = _on_cpu() if interpret is None else interpret
-    (m, k), n = x_q.shape, w_q.shape[1]
+    (m, k), n = x_q.shape, sw.shape[0]
     if None in (bm, bn, bk):
         cbm, cbn, cbk = quant_matmul_blocks(m, k, n)
         bm, bn, bk = bm or cbm, bn or cbn, bk or cbk

@@ -5,8 +5,10 @@ bn), K/bk) with the K dimension innermost ('arbitrary') accumulating into a
 VMEM scratch, also when K is one block (as fast on a v5e).  M and N may
 end in a ragged block: Pallas pads the reads and drops the writes past the
 edge, and an output element reads only its own row of x and column of w, so
-the padding never reaches a written element.  K may not: padding there
-would enter every sum (ops.py zero-pads K to a block multiple).
+the padding never reaches a written element.  For the same reason w may
+hold more columns than the output (N is the column steps' length).  K may
+not: padding there would enter every sum (ops.py zero-pads K to a block
+multiple).
 """
 from __future__ import annotations
 
@@ -56,14 +58,16 @@ def _kernel(x_ref, w_ref, sw_ref, sx_ref, o_ref, acc_ref, *, nk: int):
 
 def quant_matmul(x_q, w_q, sx, sw, *, bm=DEFAULT_BM, bn=DEFAULT_BN,
                  bk=DEFAULT_BK, interpret=False):
-    """x_q (M,K) int8, w_q (K,N) int8, sx scalar f32, sw (N,) f32 -> (M,N) f32.
+    """x_q (M,K) int8, w_q (K,N') int8 with N' >= N, sx scalar f32, sw (N,)
+    f32 -> (M,N) f32.
 
     K must be a multiple of ``bk``; M and N need not be multiples of
     ``bm`` / ``bn``.
     """
     m, k = x_q.shape
-    k2, n = w_q.shape
-    assert k == k2 and k % bk == 0, (x_q.shape, w_q.shape, bk)
+    (k2, n_w), n = w_q.shape, sw.shape[0]
+    assert k == k2 and k % bk == 0 and n_w >= n, (x_q.shape, w_q.shape,
+                                                   sw.shape, bk)
     nk = k // bk
     grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), nk)
     return pl.pallas_call(

@@ -32,6 +32,7 @@ class AttnConfig:
     bias: bool = False
     causal: bool = True
     rotary: bool = True
+    scale: float | None = None      # softmax scale; None: head_dim ** -0.5
 
 
 def init_attn(key, cfg: AttnConfig, dtype=jnp.bfloat16):
@@ -60,7 +61,8 @@ def _grouped_scores_softmax_out(q, k, v, mask, scale):
     return jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v.dtype), v)
 
 
-def full_attention(q, k, v, *, causal, window=None, q_pos0=0, kv_len=None):
+def full_attention(q, k, v, *, causal, window=None, q_pos0=0, kv_len=None,
+                   scale=None):
     """Unchunked reference path (small S / decode).
 
     ``q_pos0`` and ``kv_len`` may be scalars (one position for the whole
@@ -70,7 +72,7 @@ def full_attention(q, k, v, *, causal, window=None, q_pos0=0, kv_len=None):
     built per slot)."""
     B, Sq, KVH, G, hd = q.shape
     Sk = k.shape[1]
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     mask = None
     per_slot = jnp.ndim(q_pos0) == 1 or jnp.ndim(kv_len) == 1
     if per_slot:                            # (B,Sq,Sk)-shaped index grids
@@ -95,7 +97,7 @@ def full_attention(q, k, v, *, causal, window=None, q_pos0=0, kv_len=None):
 
 
 def chunked_attention(q, k, v, *, causal=True, window=None,
-                      q_chunk=512, k_chunk=1024, kv_len=None):
+                      q_chunk=512, k_chunk=1024, kv_len=None, scale=None):
     """Double-chunked online-softmax attention (flash-style, pure jnp).
 
     Memory per step is O(q_chunk * k_chunk); the causal upper triangle is
@@ -107,7 +109,7 @@ def chunked_attention(q, k, v, *, causal=True, window=None,
     k_chunk = min(k_chunk, Sk)
     assert Sq % q_chunk == 0 and Sk % k_chunk == 0
     nq, nk = Sq // q_chunk, Sk // k_chunk
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
 
     qc = q.reshape(B, nq, q_chunk, KVH, G, hd).transpose(1, 0, 2, 3, 4, 5)
     kc = k.reshape(B, nk, k_chunk, KVH, hd).transpose(1, 0, 2, 3, 4)
@@ -278,12 +280,13 @@ def gqa(p, x, positions, cfg: AttnConfig, *, cache=None, cache_index=None,
         # long prefill: chunked flash attention directly over the (updated)
         # cache buffers; cache-backed prefill starts at position 0
         out = chunked_attention(qg, k, v, causal=cfg.causal,
-                                window=cfg.sliding_window, kv_len=kv_len)
+                                window=cfg.sliding_window, kv_len=kv_len,
+                                scale=cfg.scale)
     else:
         q_pos0 = cache_index if cache is not None else 0
         out = full_attention(qg, k, v, causal=cfg.causal,
                              window=cfg.sliding_window,
-                             q_pos0=q_pos0, kv_len=kv_len)
+                             q_pos0=q_pos0, kv_len=kv_len, scale=cfg.scale)
     out = out.reshape(B, S, H * hd)
     return L.dense(p["wo"], out, _j(name, "wo")), new_cache
 

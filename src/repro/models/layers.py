@@ -84,6 +84,8 @@ def act_fn(name: str):
         return lambda x: jnp.square(jax.nn.relu(x))
     if name == "gelu":
         return jax.nn.gelu
+    if name == "gelu_exact":  # erf form (torch's nn.GELU default)
+        return lambda x: jax.nn.gelu(x, approximate=False)
     raise ValueError(name)
 
 

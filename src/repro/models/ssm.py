@@ -4,7 +4,8 @@ All recurrences are O(T) scans with O(1) per-token state, which is what makes
 these archs eligible for the long_500k decode shape (DESIGN.md §4).
 
 State conventions (decode caches):
-  mamba2 : {"ssm": (B, H, hd, N), "conv": (B, K-1, conv_dim)}
+  mamba2 : {"ssm": (B, H, hd, N), "conv": (B, K-1, conv_dim)};
+           conv_dim = d_inner + 2 * ngroups * N
   mlstm  : {"C": (B, H, hd, hd), "n": (B, H, hd), "m": (B, H)}
   slstm  : {"c","n","h": (B, H, hd), "m": (B, H)}
 """
@@ -29,6 +30,7 @@ class Mamba2Config:
     expand: int = 2
     head_dim: int = 64
     conv_kernel: int = 4
+    ngroups: int = 1       # groups of B and C; head h reads h // (H / G)
 
     @property
     def d_inner(self):
@@ -40,13 +42,13 @@ class Mamba2Config:
 
     @property
     def conv_dim(self):
-        return self.d_inner + 2 * self.d_state
+        return self.d_inner + 2 * self.ngroups * self.d_state
 
 
 def init_mamba2(key, cfg: Mamba2Config, dtype=jnp.bfloat16):
     ks = jax.random.split(key, 5)
     d, di, N, H = cfg.d_model, cfg.d_inner, cfg.d_state, cfg.n_heads
-    proj_out = 2 * di + 2 * N + H  # z, x, B, C, dt
+    proj_out = 2 * di + 2 * cfg.ngroups * N + H  # z, x, B, C, dt
     return {
         "in_proj": L.init_dense(ks[0], d, proj_out, dtype),
         "conv_w": (jax.random.normal(ks[1], (cfg.conv_kernel, cfg.conv_dim))
@@ -135,6 +137,53 @@ def _ssd_chunked(xs, Bt, Ct, dt, la, h0, chunk=None):
     return hT, y
 
 
+def _ssm(xs, Bt, Ct, dt, la, h0):
+    """The selective state space of one group: xs (B,S,H,P), Bt/Ct (B,S,N)
+    shared by all H heads, dt/la (B,S,H), h0 (B,H,P,N) f32.  Returns
+    (h_final, y (B,S,H,P) f32)."""
+    if xs.shape[1] > 1:
+        # chunkwise SSD (parallel within chunks, O(S*Q) not O(S) scan steps;
+        # backward stores only per-chunk states -> bounded memory)
+        return _ssd_chunked(xs, Bt, Ct, dt, la, h0)
+    dA = jnp.exp(la)
+
+    def step(h, inp):
+        xs_t, B_t, C_t, dA_t, dt_t = inp
+        dBx = jnp.einsum("bhp,bn,bh->bhpn", xs_t.astype(jnp.float32),
+                         B_t.astype(jnp.float32), dt_t)
+        h = h * dA_t[..., None, None] + dBx
+        yt = jnp.einsum("bhpn,bn->bhp", h, C_t.astype(jnp.float32))
+        return h, yt
+    inps = (xs.transpose(1, 0, 2, 3), Bt.transpose(1, 0, 2),
+            Ct.transpose(1, 0, 2), dA.transpose(1, 0, 2),
+            dt.transpose(1, 0, 2))
+    hT, ys = jax.lax.scan(step, h0, inps)
+    return hT, ys.transpose(1, 0, 2, 3)                         # (B,S,H,hd)
+
+
+def _grouped_ssm(G, xs, Bt, Ct, dt, la, h0):
+    """G groups of B and C: the state space of each group over its H/G
+    heads (head h reads group h // (H/G)), mapped over the groups."""
+    B, S, H, P = xs.shape
+    N = Bt.shape[-1] // G
+    heads = lambda a, ax: a.reshape(a.shape[:ax] + (G, H // G) +
+                                    a.shape[ax + 1:])
+    hT, y = jax.vmap(_ssm, in_axes=(2, 2, 2, 2, 2, 1), out_axes=(1, 2))(
+        heads(xs, 2), Bt.reshape(B, S, G, N), Ct.reshape(B, S, G, N),
+        heads(dt, 2), heads(la, 2), heads(h0, 1))
+    return hT.reshape(B, H, P, N), y.reshape(B, S, H, P)
+
+
+def _group_norm(p, y, groups):
+    """RMSNorm over each of ``groups`` equal slices of the channels (the
+    gated norm of a grouped Mamba2); one group is the plain RMSNorm."""
+    if groups == 1:
+        return L.norm(p, y)
+    yg = y.reshape(*y.shape[:-1], groups, -1)
+    scale = p["scale"].reshape(groups, -1)
+    return L.norm({"scale": scale}, yg).reshape(y.shape)
+
+
 def mamba2(p, x, cfg: Mamba2Config, state=None, name=None, length_mask=None):
     """x: (B,S,D). Returns (y, new_state). Recurrent selective-state scan.
 
@@ -144,7 +193,8 @@ def mamba2(p, x, cfg: Mamba2Config, state=None, name=None, length_mask=None):
     (see `_ssd_chunked`), and the conv carry keeps each slot's last valid
     window.  Outputs at masked positions are garbage by contract."""
     B, S, D = x.shape
-    di, N, H, hd = cfg.d_inner, cfg.d_state, cfg.n_heads, cfg.head_dim
+    di, H, hd = cfg.d_inner, cfg.n_heads, cfg.head_dim
+    GN = cfg.ngroups * cfg.d_state
     zxbcdt = L.dense(p["in_proj"], x, _j(name, "in_proj"))
     z = zxbcdt[..., :di]
     xbc = zxbcdt[..., di:di + cfg.conv_dim]
@@ -158,8 +208,8 @@ def mamba2(p, x, cfg: Mamba2Config, state=None, name=None, length_mask=None):
                                  lengths=lengths)
     xbc = jax.nn.silu(xbc)
     xs = constrain(xbc[..., :di].reshape(B, S, H, hd), "act")
-    Bt = xbc[..., di:di + N]                                    # (B,S,N)
-    Ct = xbc[..., di + N:]                                      # (B,S,N)
+    Bt = xbc[..., di:di + GN]                                   # (B,S,G*N)
+    Ct = xbc[..., di + GN:]                                     # (B,S,G*N)
 
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])  # (B,S,H)
     if length_mask is not None:
@@ -167,30 +217,16 @@ def mamba2(p, x, cfg: Mamba2Config, state=None, name=None, length_mask=None):
     A = -jnp.exp(p["A_log"])                                    # (H,)
 
     h0 = (state["ssm"] if state is not None
-          else jnp.zeros((B, H, hd, N), jnp.float32))
+          else jnp.zeros((B, H, hd, cfg.d_state), jnp.float32))
 
     la = dt * A                                                 # (B,S,H) <= 0
-    if S > 1:
-        # chunkwise SSD (parallel within chunks, O(S*Q) not O(S) scan steps;
-        # backward stores only per-chunk states -> bounded memory)
-        hT, y = _ssd_chunked(xs, Bt, Ct, dt, la, h0)
+    if cfg.ngroups == 1:
+        hT, y = _ssm(xs, Bt, Ct, dt, la, h0)
     else:
-        dA = jnp.exp(la)
-        def step(h, inp):
-            xs_t, B_t, C_t, dA_t, dt_t = inp
-            dBx = jnp.einsum("bhp,bn,bh->bhpn", xs_t.astype(jnp.float32),
-                             B_t.astype(jnp.float32), dt_t)
-            h = h * dA_t[..., None, None] + dBx
-            yt = jnp.einsum("bhpn,bn->bhp", h, C_t.astype(jnp.float32))
-            return h, yt
-        inps = (xs.transpose(1, 0, 2, 3), Bt.transpose(1, 0, 2),
-                Ct.transpose(1, 0, 2), dA.transpose(1, 0, 2),
-                dt.transpose(1, 0, 2))
-        hT, ys = jax.lax.scan(step, h0, inps)
-        y = ys.transpose(1, 0, 2, 3)                            # (B,S,H,hd)
+        hT, y = _grouped_ssm(cfg.ngroups, xs, Bt, Ct, dt, la, h0)
     y = y + p["D"][None, None, :, None] * xs.astype(jnp.float32)
     y = y.reshape(B, S, di).astype(x.dtype) * jax.nn.silu(z)
-    y = L.norm(p["norm"], y)
+    y = _group_norm(p["norm"], y, cfg.ngroups)
     out = L.dense(p["out_proj"], y, _j(name, "out_proj"))
     new_state = {"ssm": hT, "conv": new_conv}
     return out, new_state

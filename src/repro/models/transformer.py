@@ -5,7 +5,10 @@ Layers are organized as ``pattern_repeats`` repeats of the config's block
 params, leading axis R) — this keeps HLO size and compile time bounded for
 40-layer x 8k-wide archs (DESIGN.md §5).  zamba2's two remainder blocks run
 unscanned; its shared transformer block's weights are closure-captured by the
-scan body (shared across repeats), with per-use input norms stacked.
+scan body (shared across repeats), with per-use input norms stacked.  The
+``hybrid`` kind (Zamba2-7B) holds its ``mem_blocks`` shared blocks under
+``params["shared"]`` (a list), used in turn by the hybrid layers, and reads
+the token embeddings (the backbone's input) beside the hidden state.
 
 Public API (cfg: ArchConfig is static/hashable):
   init_lm(key, cfg)                          -> params
@@ -48,7 +51,7 @@ def _attn_cfg(cfg: ArchConfig, causal=True, cross=False) -> A.AttnConfig:
         d_model=cfg.d_model, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
         head_dim=cfg.hd, rope_theta=cfg.rope_theta,
         sliding_window=None if cross else cfg.sliding_window,
-        causal=causal and not cross, rotary=not cross)
+        causal=causal and not cross, rotary=not cross, scale=cfg.attn_scale)
 
 
 def _mla_cfg(cfg: ArchConfig) -> A.MLAConfig:
@@ -60,7 +63,8 @@ def _mla_cfg(cfg: ArchConfig) -> A.MLAConfig:
 
 
 def _mamba_cfg(cfg: ArchConfig) -> S.Mamba2Config:
-    return S.Mamba2Config(d_model=cfg.d_model, d_state=cfg.ssm_state)
+    return S.Mamba2Config(d_model=cfg.d_model, d_state=cfg.ssm_state,
+                          ngroups=cfg.mamba_ngroups)
 
 
 def _xlstm_cfg(cfg: ArchConfig) -> S.XLSTMConfig:
@@ -119,6 +123,13 @@ def init_block(key, cfg: ArchConfig, kind: str, layer_idx: int = 0):
     elif kind == "shared_attn":
         # per-use input norm only; weights live in params["shared"]
         pass
+    elif kind == "hybrid":
+        # the layer's Mamba2 and its input norm, and the use's own weights:
+        # the LoRA on the shared FFN's gate/up and the output projection
+        p["mamba"] = S.init_mamba2(ks[0], _mamba_cfg(cfg), dt)
+        p["lora_a"] = L.init_dense(ks[1], d, cfg.adapter_rank, dt)
+        p["lora_b"] = L.init_dense(ks[2], cfg.adapter_rank, 2 * cfg.d_ff, dt)
+        p["linear"] = L.init_dense(ks[3], d, d, dt)
     else:
         raise ValueError(kind)
     return p
@@ -135,11 +146,55 @@ def init_shared_block(key, cfg: ArchConfig):
     }
 
 
+def init_hybrid_block(key, cfg: ArchConfig):
+    """One of the ``hybrid`` kind's shared blocks: attention over the
+    2*d_model-wide concat(x, embeddings) back to d_model, then a gated FFN
+    whose gate and up projections are one (d_model, 2*d_ff) matrix."""
+    dt = _dtype(cfg)
+    ks = jax.random.split(key, 4)
+    d, hd = cfg.d_model, cfg.hd
+    attn = A.init_attn(ks[0], dataclasses.replace(_attn_cfg(cfg),
+                                                  d_model=2 * d), dt)
+    attn["wo"] = L.init_dense(ks[1], cfg.n_heads * hd, d, dt)
+    return {
+        "norm1": L.init_norm(2 * d, dt, cfg.norm),
+        "attn": attn,
+        "norm2": L.init_norm(d, dt, cfg.norm),
+        "ffn": {"gate_up": L.init_dense(ks[2], d, 2 * cfg.d_ff, dt),
+                "down": L.init_dense(ks[3], cfg.d_ff, d, dt)},
+    }
+
+
+def hybrid_blocks(cfg: ArchConfig) -> dict:
+    """The shared block each hybrid layer uses, keyed ``("units", i)`` by
+    pattern position and ``("rem", i)`` by remainder position: the u-th
+    hybrid layer of the stack uses block u mod ``mem_blocks``.  A pattern
+    whose repeats would use different blocks at one position is refused:
+    the scan body must name its block statically."""
+    period = len(cfg.pattern)
+    reps = cfg.n_layers // period
+    places = [("units", i) for _ in range(reps) for i in range(period)] + \
+        [("rem", i) for i in range(cfg.n_layers - reps * period)]
+    out: dict = {}
+    u = 0
+    for place in places:
+        if cfg.pattern[place[1] % period] != "hybrid":
+            continue
+        b = u % cfg.mem_blocks
+        if out.setdefault(place, b) != b:
+            raise ValueError(
+                f"{cfg.name}: pattern position {place[1]} uses shared blocks "
+                f"that change from repeat to repeat ({cfg.mem_blocks} blocks)")
+        u += 1
+    return out
+
+
 # --------------------------------------------------------------- block apply
 
 def block_apply(p, x, kind, cfg: ArchConfig, positions, *, cache=None,
                 cache_index=None, cross_kv=None, chunked=False, shared=None,
-                name=None, length_mask=None, pages=None):
+                name=None, length_mask=None, pages=None, emb=None,
+                block=None):
     """One block. Returns (x, new_cache, aux_loss).
 
     ``name`` is the block's params-pytree path prefix (``"units/3"``,
@@ -158,7 +213,13 @@ def block_apply(p, x, kind, cfg: ArchConfig, positions, *, cache=None,
     ``pages`` (B, W) switches the attention KV leaves to the paged layout
     (shared page pool + per-slot page table, `attention.paged_update` /
     `paged_gather`); per-slot state (recurrent, encoder memory) is O(1) per
-    slot and stays slot-indexed either way."""
+    slot and stays slot-indexed either way.
+
+    A ``hybrid`` block runs shared block ``block`` of ``shared`` over
+    concat(x, ``emb``) and adds its output, through the use's ``linear``,
+    to its Mamba2 layer's input: x + Mamba2(RMSNorm(x + y)).  Its cache
+    holds the attention KV ("k", "v") and the Mamba2 state ("ssm",
+    "conv")."""
     aux = 0.0
     if kind in ("attn", "mla"):
         h = L.norm(p["norm1"], x, cfg.norm)
@@ -252,6 +313,37 @@ def block_apply(p, x, kind, cfg: ArchConfig, positions, *, cache=None,
                       L.norm(shared["norm2"], x, cfg.norm), cfg.act,
                       "shared/ffn")
         return x, nc, aux
+    if kind == "hybrid":
+        sb, sname = shared[block], f"shared/{block}"
+        kv = state = nc = None
+        if cache is not None:
+            kv = {"k": cache["k"], "v": cache["v"]}
+            state = {"ssm": cache["ssm"], "conv": cache["conv"]}
+        with jax.named_scope("shared_block"):
+            h = L.norm(sb["norm1"], jnp.concatenate([x, emb], axis=-1),
+                       cfg.norm)
+            ao, nkv = A.gqa(sb["attn"], h, positions, _attn_cfg(cfg),
+                            cache=kv, cache_index=cache_index,
+                            chunked=chunked, pages=pages,
+                            write_mask=length_mask, name=_j(sname, "attn"))
+            h = L.norm(sb["norm2"], ao, cfg.norm)
+            lora = L.dense(p["lora_b"],
+                           L.dense(p["lora_a"], h, _j(name, "lora_a")),
+                           _j(name, "lora_b"))
+            gate, up = jnp.split(
+                L.dense(sb["ffn"]["gate_up"], h, _j(sname, "ffn/gate_up"))
+                + lora, 2, axis=-1)
+            y = L.dense(sb["ffn"]["down"], L.act_fn(cfg.act)(gate) * up,
+                        _j(sname, "ffn/down"))
+        y = L.dense(p["linear"], y, _j(name, "linear"))
+        with jax.named_scope("mixer"):
+            mo, ns = S.mamba2(p["mamba"], L.norm(p["norm1"], x + y, cfg.norm),
+                              _mamba_cfg(cfg), state=state,
+                              name=_j(name, "mamba"),
+                              length_mask=length_mask)
+        if cache is not None:
+            nc = {**nkv, **ns}
+        return x + mo, nc, aux
     raise ValueError(kind)
 
 
@@ -294,6 +386,10 @@ def init_lm(key, cfg: ArchConfig):
                          for i in range(rem)]
     if "shared_attn" in cfg.pattern:
         params["shared"] = init_shared_block(keys[4], cfg)
+    if "hybrid" in cfg.pattern:
+        hybrid_blocks(cfg)          # refuses blocks that change per repeat
+        params["shared"] = [init_hybrid_block(k, cfg) for k in
+                            jax.random.split(keys[4], cfg.mem_blocks)]
     if cfg.moe_first_dense:
         # deepseek: layer 0 replaced by a dense-FFN copy, unscanned
         params["first_dense"] = init_block(keys[5], cfg, cfg.pattern[0],
@@ -359,6 +455,8 @@ def backbone(params, cfg: ArchConfig, x, positions, *, caches=None,
     from repro.distributed.sharding import constrain
     period = len(cfg.pattern)
     shared = params.get("shared")
+    blocks = hybrid_blocks(cfg) if "hybrid" in cfg.pattern else {}
+    emb = x
 
     def unit_fn(carry, xs):
         x, aux = carry
@@ -381,7 +479,8 @@ def backbone(params, cfg: ArchConfig, x, positions, *, caches=None,
                     blk, x, kind, cfg, positions, cache=c,
                     cache_index=cache_index, cross_kv=ckv, chunked=chunked,
                     shared=shared, name=f"units/{i}",
-                    length_mask=length_mask, pages=pages)
+                    length_mask=length_mask, pages=pages, emb=emb,
+                    block=blocks.get(("units", i)))
                 aux = aux + a
                 new_cache.append(nc)
         x = constrain(x, "act")
@@ -414,7 +513,8 @@ def backbone(params, cfg: ArchConfig, x, positions, *, caches=None,
             x, nc, a = block_apply(blk, x, kind, cfg, positions, cache=c,
                                    cache_index=cache_index, chunked=chunked,
                                    shared=shared, name=f"rem/{i}",
-                                   length_mask=length_mask, pages=pages)
+                                   length_mask=length_mask, pages=pages,
+                                   emb=emb, block=blocks.get(("rem", i)))
             aux = aux + a
             new_rem.append(nc)
 
@@ -496,6 +596,9 @@ def lm_loss(params, cfg: ArchConfig, batch, remat=False):
 
 def _block_cache_spec(cfg: ArchConfig, kind: str, B: int, S_max: int):
     dt = jnp.int8 if cfg.kv_cache_dtype == "int8" else jnp.bfloat16
+    if kind == "hybrid":
+        return {**_block_cache_spec(cfg, "attn", B, S_max),
+                **_block_cache_spec(cfg, "mamba", B, S_max)}
     if kind in ("attn", "dec", "shared_attn"):
         spec = {"k": ((B, S_max, cfg.n_kv_heads, cfg.hd), dt),
                 "v": ((B, S_max, cfg.n_kv_heads, cfg.hd), dt)}
@@ -512,8 +615,10 @@ def _block_cache_spec(cfg: ArchConfig, kind: str, B: int, S_max: int):
                 "cv": ((B, cfg.frontend_tokens, cfg.n_kv_heads, cfg.hd), dt)}
     if kind == "mamba":
         mc = _mamba_cfg(cfg)
+        # the convolution window holds the in_proj output as computed,
+        # whatever the KV cache's dtype
         return {"ssm": ((B, mc.n_heads, mc.head_dim, mc.d_state), jnp.float32),
-                "conv": ((B, mc.conv_kernel - 1, mc.conv_dim), dt)}
+                "conv": ((B, mc.conv_kernel - 1, mc.conv_dim), _dtype(cfg))}
     if kind == "mlstm":
         xc = _xlstm_cfg(cfg)
         return {"C": ((B, xc.n_heads, xc.head_dim, xc.head_dim), jnp.float32),
@@ -588,7 +693,7 @@ def init_cache(cfg: ArchConfig, B: int, S_max: int):
 # else (recurrent state, conv tails, encoder memory) is O(1)-per-slot and
 # stays slot-indexed in both layouts.
 _PAGED_KEYS = frozenset({"k", "v", "latent"})
-_SEQ_KINDS = frozenset({"attn", "dec", "shared_attn", "mla"})
+_SEQ_KINDS = frozenset({"attn", "dec", "shared_attn", "mla", "hybrid"})
 
 
 def _paged_block_cache_spec(cfg: ArchConfig, kind: str, B: int,

@@ -25,6 +25,7 @@ MODEL_FLOPS / HLO_FLOPs measures how much compiled compute is "useful".
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 from pathlib import Path
 from typing import Dict
@@ -49,6 +50,7 @@ SHAPE_META = {
 
 # --------------------------------------------------------- parameter counts
 
+@functools.lru_cache(maxsize=None)
 def param_counts(cfg: ArchConfig) -> Dict[str, float]:
     """(total, active) parameter counts from the real init (eval_shape)."""
     from repro.models import transformer as T
@@ -121,14 +123,23 @@ def _block_fwd_flops(cfg: ArchConfig, kind: str, B: int, S: int,
         f += _attn_flops(B, S, Tf, H, hd, hd)
         f += (3 if cfg.gated_ffn else 2) * lin(D, cfg.d_ff)
     elif kind == "mamba":
-        di, N = 2 * D, cfg.ssm_state
+        di, N, G = 2 * D, cfg.ssm_state, cfg.mamba_ngroups
         Hm, P = di // 64, 64
-        f += lin(D, 2 * di + 2 * N + Hm) + lin(di, D)
+        f += lin(D, 2 * di + 2 * G * N + Hm) + lin(di, D)
         Q = min(256, S)
         nchunks = max(1, S // Q)
-        # SSD chunk math: CB (2BQ^2N), W-apply (2BQ^2 Hm P), state io
-        f += nchunks * (2.0 * B * Q * Q * N + 2.0 * B * Q * Q * Hm * P +
+        # SSD chunk math: CB (2BQ^2N per group), W-apply (2BQ^2 Hm P),
+        # state io
+        f += nchunks * (2.0 * B * Q * Q * N * G + 2.0 * B * Q * Q * Hm * P +
                         4.0 * B * Q * Hm * P * N)
+    elif kind == "hybrid":
+        # its Mamba2, the shared block over the 2D-wide concat, the use's
+        # LoRA and output projection
+        f += _block_fwd_flops(cfg, "mamba", B, S, cache_len)
+        f += lin(2 * D, H * hd) + 2 * lin(2 * D, KVH * hd) + lin(H * hd, D)
+        f += _attn_flops(B, S, Sk, H, hd, hd)
+        F, r = cfg.d_ff, cfg.adapter_rank
+        f += lin(D, 2 * F) + lin(F, D) + lin(D, r) + lin(r, 2 * F) + lin(D, D)
     elif kind in ("mlstm", "slstm"):
         di = 2 * D
         Hx, hx = cfg.n_heads, di // cfg.n_heads

@@ -32,8 +32,10 @@ weights (e.g. a ternary-heavy "draft" and an int8-heavy "target" mapping)
 protocol of `repro.models`
 (``backend(name, p, x, conv=...) -> y | None``): plans are resolved by the
 layer's pytree path — a static string — so planned execution traces cleanly
-under ``jax.jit`` (weights may be tracers; the prepared arrays are baked
-into the trace as constants).  The active variant is the trace-static key
+under ``jax.jit``.  A jitted caller passes the prepared arrays in as
+arguments (``weights()``, and ``traced(weights)`` around the traced body);
+outside that context a trace closes over them, as constants.  The active
+variant is the trace-static key
 published via ``repro.models._backend.plan_variant`` (default variant
 outside any context), and prepared weight buffers are DEDUPLICATED across
 variants wherever a layer's (plan, weight, domain-bits, block) tuple
@@ -52,6 +54,7 @@ planned kernel, bias included — no model code forks.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import json
 from typing import Any, Dict, List, Tuple
@@ -248,6 +251,12 @@ def prepare_layer(lp: LayerPlan, w, b=None,
                      KERNEL_SPLIT_TERNARY):
         w_q, sw = _per_column_quant(lp, w_perm.astype(jnp.float32),
                                     domain_bits)
+    if lp.kernel == KERNEL_QUANT:
+        # zero columns up to a multiple of 128 lanes: a TPU lays out an
+        # array whose last dim is ragged with that dim major, and the
+        # kernel, which reads it row-major, would then copy it on every
+        # call that takes it as an argument
+        w_q = jnp.pad(w_q, ((0, 0), (0, -lp.c_out % 128)))
     if lp.kernel == KERNEL_SPLIT:
         w_bf16 = w_perm.astype(jnp.bfloat16)
     if lp.kernel == KERNEL_SPLIT_TERNARY:
@@ -310,7 +319,7 @@ def execute_layer(prep: PreparedLayer, x, *, interpret=None,
         if reference:
             fn = (ref.ternary_matmul_ref if lp.kernel == KERNEL_TERNARY
                   else ref.quant_matmul_ref)
-            y = fn(x_q, prep.w_q, sx, prep.sw)
+            y = fn(x_q, prep.w_q[..., :lp.c_out], sx, prep.sw)
         else:
             fn = (ops.ternary_matmul_op if lp.kernel == KERNEL_TERNARY
                   else ops.quant_matmul_op)
@@ -437,6 +446,14 @@ _DROPS_FP_STACK = (KERNEL_QUANT, KERNEL_TERNARY, KERNEL_SPLIT_TERNARY,
                    KERNEL_SPLIT)
 
 
+def _without_fp(prep: PreparedLayer) -> PreparedLayer:
+    """``prep`` without the full-precision weight copy its kernel never
+    reads: bound entries never route to the eager `reference_layer`."""
+    if prep.plan.kernel in _DROPS_FP_STACK:
+        return dataclasses.replace(prep, w_perm=None)
+    return prep
+
+
 class _StackedPrepared:
     """Homogeneous per-repeat `PreparedLayer`s stacked on a leading R axis;
     ``at(r)`` slices repeat ``r`` (r may be a traced scan index — this is
@@ -490,11 +507,7 @@ class _SingleRepeat:
     no leading stack axis, no per-iteration dynamic gather."""
 
     def __init__(self, prep: PreparedLayer):
-        # same fp32-copy drop as the other stack containers: stacked
-        # entries never route to reference_layer, so w_perm is dead weight
-        if prep.plan.kernel in _DROPS_FP_STACK:
-            prep = dataclasses.replace(prep, w_perm=None)
-        self.prep = prep
+        self.prep = _without_fp(prep)
         self.conv_shape = prep.conv_shape
         self.conv_groups = prep.plan.groups
 
@@ -567,11 +580,7 @@ class _SwitchPrepared:
     structure."""
 
     def __init__(self, preps: List[PreparedLayer]):
-        # stacked repeats never read the fp32 weights (split reads its bf16
-        # cast), so don't keep their (K, N) float copies alive
-        self.preps = [dataclasses.replace(p, w_perm=None)
-                      if p.plan.kernel in _DROPS_FP_STACK else p
-                      for p in preps]
+        self.preps = [_without_fp(p) for p in preps]
         self.conv_shape = preps[0].conv_shape
         self.conv_groups = preps[0].plan.groups
 
@@ -617,27 +626,31 @@ _PREP_ARRAY_FIELDS = ("inv", "w_perm", "b", "w_q", "sw", "w_bf16",
                       "w_t_packed", "act_scale", "act_sx")
 
 
-def _entry_arrays(entry):
-    """Every device array a bound entry (plain or stacked) keeps alive."""
+def _entry_slots(entry):
+    """(holder, attribute) of every device array a bound entry (plain or
+    stacked) keeps alive."""
     if isinstance(entry, PreparedLayer):
         for f in _PREP_ARRAY_FIELDS:
-            a = getattr(entry, f)
-            if a is not None:
-                yield a
+            if getattr(entry, f) is not None:
+                yield entry, f
     elif isinstance(entry, _SingleRepeat):
-        yield from _entry_arrays(entry.prep)
+        yield from _entry_slots(entry.prep)
     elif isinstance(entry, _StackedPrepared):
-        for a in (entry._inv, entry._w_perm, entry._w_bf16,
-                  entry._w_t_packed, entry._b, entry._w_q, entry._sw,
-                  entry._act_scale, entry._act_sx):
-            if a is not None:
-                yield a
+        for f in _PREP_ARRAY_FIELDS:
+            if getattr(entry, "_" + f) is not None:
+                yield entry, "_" + f
     elif isinstance(entry, _GroupedPrepared):
         for g in entry.groups:
-            yield from _entry_arrays(g)
+            yield from _entry_slots(g)
     elif isinstance(entry, _SwitchPrepared):
         for p in entry.preps:
-            yield from _entry_arrays(p)
+            yield from _entry_slots(p)
+
+
+def _entry_arrays(entry):
+    """Every device array a bound entry (plain or stacked) keeps alive."""
+    for holder, attr in _entry_slots(entry):
+        yield getattr(holder, attr)
 
 
 def prepared_nbytes(entries) -> int:
@@ -706,10 +719,9 @@ class _BoundPlan:
                    int(plan.block_n))
             prep = owner._memo(
                 key, variant, lp.name,
-                lambda: prepare_layer(lp, _layer_weight(node),
-                                      b=node.get("b"),
-                                      domain_bits=domain_bits,
-                                      block_n=plan.block_n))
+                lambda: _without_fp(prepare_layer(
+                    lp, _layer_weight(node), b=node.get("b"),
+                    domain_bits=domain_bits, block_n=plan.block_n)))
             self.by_name[lp.name] = prep
             self.bound.append(lp.name)
         for base, entries in sorted(stacked.items()):
@@ -809,6 +821,42 @@ class PlanSet:
         for vname, plan in variants.items():
             self._variants[vname] = _BoundPlan(vname, plan, params, handle,
                                                self)
+        # every unique prepared array, and where each one is held
+        self._weights: List[jax.Array] = []
+        self._slots: List[Tuple[Any, str, int]] = []
+        index: Dict[int, int] = {}
+        for entry in {id(e): e for bp in self._variants.values()
+                      for e in bp.by_name.values()}.values():
+            for holder, attr in _entry_slots(entry):
+                a = getattr(holder, attr)
+                if id(a) not in index:
+                    index[id(a)] = len(self._weights)
+                    self._weights.append(a)
+                self._slots.append((holder, attr, index[id(a)]))
+
+    # ---- prepared arrays as arguments -----------------------------------
+
+    def weights(self) -> Tuple[jax.Array, ...]:
+        """Every unique prepared array of the bank, in a fixed order: what
+        a jitted caller passes in so that no executable embeds them."""
+        return tuple(self._weights)
+
+    @contextlib.contextmanager
+    def traced(self, weights):
+        """Inside a traced function that received ``weights()`` as an
+        argument: planned layers read those (traced) arrays in place of
+        the bound ones for the duration of the context."""
+        if len(weights) != len(self._weights):
+            raise ExecutionError(f"{len(weights)} prepared arrays given, "
+                                 f"the bank holds {len(self._weights)}")
+        bound = [getattr(holder, attr) for holder, attr, _ in self._slots]
+        try:
+            for holder, attr, i in self._slots:
+                setattr(holder, attr, weights[i])
+            yield
+        finally:
+            for (holder, attr, _), a in zip(self._slots, bound):
+                setattr(holder, attr, a)
 
     # ---- shared prepare cache -------------------------------------------
 

@@ -40,7 +40,9 @@ step likewise traces once).  With a `repro.runtime.PlannedBackend` passed
 as ``backend``, every jitted call executes covered projections through
 their planned split-precision kernels (the name-keyed matmul-backend
 protocol resolves statically inside jit), so engine latency IS mapped
-latency.
+latency.  Every jitted step takes the backend's prepared arrays
+(``PlanSet.weights()``) as an argument beside the params, so no
+executable embeds a weight.
 
 MULTI-PLAN SERVING — with a `repro.runtime.PlanSet` bound as ``backend``
 (N precision variants over ONE shared params pytree), the engine can
@@ -144,12 +146,16 @@ on every path:
         engine.decode.wait
         engine.decode.commit  token commit, retirement, fault handling
 
-``stats["decode_rows"]`` counts the slots each decode call ran, and
+``stats["decode_rows"]`` counts the slots each decode call ran,
+``stats["decode_kv_tokens"]`` the cached tokens their attention read (each
+slot's length after the step, times the attention layers a token passes;
+the speculative round's calls are not counted), and
 ``RequestResult.queue_s`` is a request's wait for its first admission.
 """
 from __future__ import annotations
 
 import contextlib
+import functools
 import math
 import time
 import warnings
@@ -288,6 +294,10 @@ class Engine:
                              f"got {kv_layout!r}")
         self.cfg = cfg
         self.params = params
+        # what every jitted model step takes first: the params and the
+        # backend's prepared arrays (see ``with_weights``)
+        self._model = (params, backend.weights()
+                       if hasattr(backend, "weights") else ())
         self.max_batch = int(max_batch)
         self.max_len = int(max_len)
         self.backend = backend
@@ -446,6 +456,7 @@ class Engine:
         self._kv_axes = T.cache_kv_axes(cfg)
         self._has_recurrent = any(
             ax.startswith("slot") for ax in jax.tree.leaves(self._kv_axes))
+        self._attn_uses = _attention_uses(cfg, self._kv_axes)
         self._kv_capacity_bytes, self._kv_page_bytes = self._kv_footprint()
         if sampling is not None:
             self._base_key = jax.random.PRNGKey(int(sampling.seed))
@@ -458,6 +469,18 @@ class Engine:
         self._stuck: Dict[int, int] = {}
         self._inject_slots: List[int] = []
         self._monitor: Optional[HeartbeatMonitor] = None
+
+        def with_weights(fn):
+            # the step reads the prepared arrays it was given, never the
+            # bound ones, so none is baked into its executable
+            @functools.wraps(fn)
+            def step(model, *args, **kw):
+                params, weights = model
+                ctx = (backend.traced(weights) if weights
+                       else contextlib.nullcontext())
+                with ctx:
+                    return fn(params, *args, **kw)
+            return step
 
         def pick(logits, keys):
             # greedy argmax, or per-slot sampling advancing the PRNG keys
@@ -550,11 +573,12 @@ class Engine:
                 return leaf
             return jax.tree.map(f, caches, self._kv_axes)
 
-        self._decode = jax.jit(decode_fn)
-        self._decode_paged = jax.jit(decode_paged_fn,
+        self._decode = jax.jit(with_weights(decode_fn))
+        self._decode_paged = jax.jit(with_weights(decode_paged_fn),
                                      static_argnames=("variant",))
-        self._prefill = jax.jit(prefill_fn)
-        self._chunk = jax.jit(chunk_fn, static_argnames=("variant",))
+        self._prefill = jax.jit(with_weights(prefill_fn))
+        self._chunk = jax.jit(with_weights(chunk_fn),
+                              static_argnames=("variant",))
         self._reset = jax.jit(reset_fn)
         self._copy_pages = jax.jit(copy_pages_fn)
         self._corrupt_pages = jax.jit(corrupt_pages_fn)
@@ -632,9 +656,9 @@ class Engine:
                                             variant=target_v)
                 return caches
 
-            self._draft = jax.jit(draft_fn)
-            self._verify = jax.jit(verify_fn)
-            self._replay = jax.jit(replay_fn)
+            self._draft = jax.jit(with_weights(draft_fn))
+            self._verify = jax.jit(with_weights(verify_fn))
+            self._replay = jax.jit(with_weights(replay_fn))
 
     # ---- helpers ---------------------------------------------------------
 
@@ -883,7 +907,7 @@ class Engine:
                 self._meta(r)["t_admit"] = t0
             with self._span("engine.chunk.dispatch"):
                 tok0, keys_out, batch.caches = self._prefill(
-                    self.params, prompts, lengths, batch.caches, slots_p,
+                    self._model, prompts, lengths, batch.caches, slots_p,
                     frontend, keys)
             with self._span("engine.chunk.wait"):
                 tok0 = np.asarray(tok0)       # sync: first tokens
@@ -991,7 +1015,7 @@ class Engine:
                 valid = np.zeros(B, np.int32)
                 valid[group] = valid_all[group]
                 tok, keys, ok, batch.caches = self._chunk(
-                    self.params, tokens, batch.caches, batch.fill_pos.copy(),
+                    self._model, tokens, batch.caches, batch.fill_pos.copy(),
                     valid, batch.page_table.copy(), self._fe_buf, batch.rng,
                     variant=var)
                 outs.append((group, tok, keys, ok))
@@ -1067,11 +1091,13 @@ class Engine:
                 mask = np.zeros(self.max_batch, bool)
                 mask[group] = True
                 tok, keys, ok, batch.caches = self._decode_paged(
-                    self.params, batch.last_tok, batch.caches, batch.lengths,
+                    self._model, batch.last_tok, batch.caches, batch.lengths,
                     mask, batch.page_table.copy(), batch.rng, inject,
                     variant=var)
                 outs.append((group, tok, keys, ok))
                 self.stats["decode_rows"] += len(group)
+                self.stats["decode_kv_tokens"] += self._attn_uses * int(
+                    (batch.lengths[group] + 1).sum())
         tok_all = batch.last_tok.copy()
         ok_all = np.ones(self.max_batch, bool)
         with self._span("engine.decode.wait"):
@@ -1112,12 +1138,12 @@ class Engine:
         t = time.monotonic()
         with self._span("engine.decode.dispatch"):
             drafted, batch.caches = self._draft(
-                self.params, tok0, batch.caches, fill0, batch.active.copy(),
+                self._model, tok0, batch.caches, fill0, batch.active.copy(),
                 batch.page_table.copy())
             vcount = np.zeros(self.max_batch, np.int32)
             vcount[sel] = np.minimum(k + 1, self.slot_cap - fill0[sel])
             vtok, batch.caches = self._verify(
-                self.params, tok0, drafted, batch.caches, snap, fill0,
+                self._model, tok0, drafted, batch.caches, snap, fill0,
                 vcount, batch.page_table.copy())
         self.stats["decode_rows"] += len(sel)
         with self._span("engine.decode.wait"):
@@ -1164,7 +1190,7 @@ class Engine:
             t = time.monotonic()
             with self._span("engine.decode.dispatch"):
                 batch.caches = self._replay(
-                    self.params, tok0, drafted, batch.caches, snap, fill0,
+                    self._model, tok0, drafted, batch.caches, snap, fill0,
                     replay_valid, batch.page_table.copy())
             self.stats["decode_s"] += time.monotonic() - t
 
@@ -1377,7 +1403,8 @@ class Engine:
                       "preemptions": 0, "resumes": 0, "shed_requests": 0,
                       "timeouts": 0, "faults_injected": 0,
                       "faults_detected": 0, "degrade_transitions": 0,
-                      "heartbeat_trips": 0, "decode_rows": 0}
+                      "heartbeat_trips": 0, "decode_rows": 0,
+                      "decode_kv_tokens": 0}
         if self._spec is not None:
             self.stats.update({"spec_rounds": 0, "spec_drafted": 0,
                                "spec_accepted": 0, "spec_committed": 0})
@@ -1477,9 +1504,11 @@ class Engine:
         t = time.monotonic()
         with self._span("engine.decode.dispatch"):
             tok, keys, batch.caches = self._decode(
-                self.params, batch.last_tok, batch.caches, batch.lengths,
+                self._model, batch.last_tok, batch.caches, batch.lengths,
                 batch.active, batch.rng)
         self.stats["decode_rows"] += batch.n_active
+        self.stats["decode_kv_tokens"] += self._attn_uses * int(
+            (batch.lengths[batch.active] + 1).sum())
         with self._span("engine.decode.wait"):
             tok = np.asarray(tok)                   # sync
             act = np.nonzero(batch.active)[0]
@@ -1497,8 +1526,11 @@ class Engine:
             rows = self.num_pages + 1                  # + trash page 0
             self._paged_caches = T.init_paged_cache(
                 self.cfg, self.max_batch, rows, self.page_size)
+        # the batch holds the pool while it runs: a second reference would
+        # keep the run's first pool alive beside every later one
         batch = BatchState(self.max_batch, self._paged_caches,
                            pages_per_slot=self.pages_per_slot)
+        self._paged_caches = None
         self._fe_buf = None
         t_ready: Dict[int, float] = {}
         step = 0
@@ -1585,6 +1617,20 @@ class Engine:
                 step += 1
         self._monitor = None
         self._paged_caches = batch.caches       # keep cached pages resident
+
+
+def _attention_uses(cfg, kv_axes) -> int:
+    """Attention layers a token passes through: the sequence-indexed key
+    leaves ("k", or MLA's "latent") of the cache, each stacked leaf
+    counted once per repeat."""
+    specs = T.cache_specs(cfg, 1, 1)
+    uses = 0
+    for (path, leaf), ax in zip(jax.tree_util.tree_leaves_with_path(specs),
+                                jax.tree.leaves(kv_axes)):
+        if ax.startswith("page") and \
+                getattr(path[-1], "key", None) in ("k", "latent"):
+            uses += leaf.shape[0] if ax == "page1" else 1
+    return uses
 
 
 class _DegradeController:

@@ -16,7 +16,7 @@ def _load():
 
 def test_shapes_cover_40_cells():
     assert len(SP.SHAPES) == 4
-    assert len(base.names()) == 10
+    assert len(base.names()) == 11
 
 
 def test_long500k_gating():

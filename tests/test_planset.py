@@ -380,3 +380,101 @@ def test_engine_multiplan_validation_errors(yi):
         mk(backend=bank, kv_layout="paged", slo_routes={"gold": "nope"})
     with pytest.raises(ValueError, match="requires kv_layout='paged'"):
         mk(backend=bank, kv_layout="dense", slo_routes={"gold": "draft"})
+
+
+# --------------------------------------------------------------------------
+# prepared arrays as arguments of the engine's steps
+# --------------------------------------------------------------------------
+
+_DTYPE_BYTES = {"i1": 1, "i8": 1, "ui8": 1, "i16": 2, "bf16": 2, "f16": 2,
+                "i32": 4, "ui32": 4, "f32": 4, "i64": 8, "f64": 8}
+
+
+def _largest_constant(lowered) -> int:
+    """Bytes of the largest constant in a lowered program's StableHLO."""
+    import math
+    import re
+    largest = 0
+    for line in lowered.as_text().splitlines():
+        if "stablehlo.constant" not in line:
+            continue
+        m = re.findall(r"tensor<((?:\d+x)*)([a-z]+\d+)>", line)
+        if m:
+            dims, dt = m[-1]
+            n = math.prod(int(d) for d in dims.split("x") if d)
+            largest = max(largest, n * _DTYPE_BYTES[dt])
+    return largest
+
+
+@pytest.fixture(scope="module")
+def wide(tmp_path_factory):
+    """A planned hybrid model whose FFN gate/up weights are 2 MiB in int8,
+    bound to an engine."""
+    import dataclasses
+    from repro.launch.train import emit_static_mapping
+    tmp = tmp_path_factory.mktemp("planset_wide")
+    cfg = dataclasses.replace(_reduced("zamba2-7b"), d_ff=16384)
+    params = T.init_lm(jax.random.PRNGKey(0), cfg)
+    art = emit_static_mapping(params, cfg, "tpu_v5e", tmp / "m.json",
+                              act_log_scale=3.0)
+    backend = PlannedBackend(lower(art, params=params), params)
+    eng = Engine(cfg, params, backend=backend, max_batch=2, max_len=32,
+                 page_size=8, prefill_chunk=8)
+    return cfg, params, backend, eng
+
+
+def _step_args(eng):
+    B, W = eng.max_batch, eng.pages_per_slot
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    caches = T.paged_cache_specs(eng.cfg, B, eng.num_pages + 1,
+                                 eng.page_size)
+    keys = jax.ShapeDtypeStruct((B, 2), jnp.uint32)
+    chunk = (eng._model, i32(B, eng.prefill_chunk), caches, i32(B), i32(B),
+             i32(B, W), None, keys)
+    decode = (eng._model, i32(B), caches, i32(B),
+              jax.ShapeDtypeStruct((B,), jnp.bool_), i32(B, W), keys,
+              jax.ShapeDtypeStruct((B,), jnp.float32))
+    return chunk, decode
+
+
+def test_engine_steps_embed_no_weight(wide):
+    cfg, params, backend, eng = wide
+    assert max(int(a.nbytes) for a in backend.weights()
+               if a.dtype == jnp.int8) >= 2 << 20
+    chunk, decode = _step_args(eng)
+    with eng._ctx():
+        lowered = [eng._chunk.lower(*chunk), eng._decode_paged.lower(*decode)]
+        # the same decode step closing over the bound arrays embeds them:
+        # the reading below sees a weight where one is baked in
+        closed = jax.jit(lambda p, *a: T.decode_step(
+            p, cfg, a[0], a[1], a[2], active=a[3], pages=a[4])).lower(
+                params, *decode[1:6])
+    assert all(_largest_constant(lw) < 1 << 20 for lw in lowered)
+    assert _largest_constant(closed) >= 2 << 20
+
+
+def test_weights_are_each_prepared_array_once(yi):
+    cfg, params, target, _ = yi
+    bank = PlanSet({"a": lower(target, params=params),
+                    "b": lower(target, params=params)}, params)
+    weights = bank.weights()
+    assert len({id(a) for a in weights}) == len(weights)
+    assert sum(int(a.nbytes) for a in weights) == bank.prepared_bytes()
+
+
+def test_traced_reads_the_given_arrays_then_restores(yi):
+    cfg, params, target, _ = yi
+    backend = PlannedBackend(lower(target, params=params), params,
+                             reference=True)
+    x = jnp.ones((3, cfg.d_model), jnp.float32)
+    name = next(n for n, e in backend._by_name.items()
+                if not hasattr(e, "execute"))
+    bound = backend(name, None, x)
+    weights = backend.weights()
+    with backend.traced(tuple(jnp.zeros_like(a) for a in weights)):
+        assert float(jnp.abs(backend(name, None, x)).max()) == 0.0
+    np.testing.assert_array_equal(backend(name, None, x), bound)
+    assert all(a is b for a, b in zip(backend.weights(), weights))
+    with pytest.raises(Exception, match="prepared arrays given"):
+        with backend.traced(weights[:-1]):
+            pass

@@ -659,7 +659,7 @@ def test_prepare_layer_per_domain_column_steps():
     # (they execute in int8 only as conservative block padding)
     np.testing.assert_allclose(sw[n0 + n1:], step0, rtol=1e-6)
     # codes * step reconstruct each domain's columns with ITS scale
-    deq = np.asarray(prep.w_q, np.float32) * sw[None, :]
+    deq = np.asarray(prep.w_q, np.float32)[:, :N] * sw[None, :]
     wf = np.asarray(w)
     for cols, bits, s in [(slice(0, n0), 8, ls[0]),
                           (slice(n0, n0 + n1), 2, ls[1])]:

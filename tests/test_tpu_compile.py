@@ -83,3 +83,30 @@ def test_quant_matmul_compiles_at_chosen_blocks(one_chip, m, k, n):
     compiled = jax.jit(lambda *a: ops.quant_matmul_op(
         *a, interpret=False)).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# zamba2-7b's projections (K, N): Mamba2 in_proj and out_proj, the shared
+# block's q/k/v, gate_up and down, the LoRA's up-projection, the per-use
+# linear
+ZAMBA2_7B = [(3584, 14704), (7168, 3584), (7168, 7168), (3584, 28672),
+             (14336, 3584), (128, 28672), (3584, 3584)]
+
+
+@pytest.mark.parametrize("m", [16, 4096])
+@pytest.mark.parametrize("k,n", ZAMBA2_7B)
+def test_zamba2_7b_projections_compile_at_chosen_blocks(one_chip, m, k, n):
+    test_quant_matmul_compiles_at_chosen_blocks(one_chip, m, k, n)
+
+
+@pytest.mark.parametrize("m", [16, 4096])
+def test_zamba2_7b_lora_split_compiles(one_chip, m):
+    """The LoRA's 128-wide down-projection, a min-cost split."""
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    k, n = 3584, 128
+    args = (sds((m, k), jnp.bfloat16), sds((m, k), jnp.int8),
+            sds((), jnp.float32), sds((k, n), jnp.bfloat16),
+            sds((k, n), jnp.int8), sds((n,), jnp.float32))
+    compiled = jax.jit(lambda *a: ops.split_precision_op(
+        *a, boundary=86, interpret=False)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

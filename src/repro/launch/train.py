@@ -87,7 +87,8 @@ def emit_static_mapping(params, cfg, platform, out_path, max_cout=512,
     plan_hints = plan_hints or {}
 
     def w_scale(w):
-        ls = float(quant.init_log_scale(np.asarray(w, dtype=np.float32)))
+        # the max-abs is taken where the weight lives: only a scalar moves
+        ls = float(quant.init_log_scale(jnp.asarray(w, jnp.float32)))
         return {"w_log_scales": [ls] * spec.n_domains,
                 "act_log_scale": (float(act_log_scale)
                                   if act_log_scale is not None else None)}

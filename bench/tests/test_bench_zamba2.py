@@ -3,7 +3,8 @@ CPU, at a size with two Mamba2 groups and two shared blocks used twice
 each, on the same random weights, float32 logits; and whole runs of a
 test-size `zamba2-7b` cell (`data/zamba2-7b-smoke.json`) through the
 harness: correct, its decode byte counts read, planted faults caught, the
-int4 control above the limit and the program below it."""
+int4 control above the limit and the program below it; and the decode
+roofline reader's engine calls on made-up and recorded device events."""
 import json
 import shutil
 import sys
@@ -20,8 +21,12 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import check  # noqa: E402
 import control  # noqa: E402
+import devtrace  # noqa: E402
 import harness  # noqa: E402
 import test_bench_harness as th  # noqa: E402
+from devtrace import Event  # noqa: E402
+
+roofline_reader = harness.metric_reader("decode_hbm_roofline", ROOT)
 
 CELL = "zamba2-7b-smoke.smoke"
 
@@ -208,13 +213,74 @@ def root(tmp_path_factory):
 
 
 def test_smoke_run_is_correct_and_reads_the_decode_bytes(root):
-    out = th.run(root, trace=True, cell=CELL)
+    keep = {}
+    out = th.run(root, trace=True, cell=CELL, keep=keep)
     assert out["correct"] is True and out["failed"] == 0
-    got = out["metrics"]
-    assert 0 < got["decode_kv_share"]["value"] < 100
-    assert "decode_hbm_roofline" not in got      # no chip, no peaks
+    assert "decode_hbm_roofline" not in out["metrics"]   # no chip, no peaks
     gap = out["checks"]["max_logit_gap"]
     assert 0 <= gap["value"] < gap["limit"]
+    s, ref, sizes = keep["stats"], keep["reference"], keep["sizes"]
+    # each decode call's slots read their whole cache in both hybrid uses
+    assert s["decode_kv_tokens"] >= 2 * s["decode_rows"] > 0
+    ctx = {"stats": s, "reference": ref, "sizes": sizes, "layers": []}
+    head = 2.0 * sizes["d_model"] * sizes["vocab"]
+    assert roofline_reader.least_bytes(ctx) == pytest.approx(
+        s["decode_kv_tokens"] * ref.kv_bytes_per_token(sizes)
+        + head * s["decode_steps"]
+        + 2 * s["decode_rows"] * ref.state_bytes_per_slot(sizes))
+
+
+def _events(*calls):
+    """Device events of engine calls 1 ms apart, each ``(quant_matmul
+    rows or None, ops)`` run back to back, ops of 10 us."""
+    out, t = [], 0.0
+    for rows, ops in calls:
+        for i in range(ops):
+            name = "%fusion.1 = bf16[8,128]{1,0} fusion(...)"
+            if rows is not None and i == 0:
+                name = (f"%quant_matmul_op.3 = f32[{rows},2048]{{1,0}} "
+                        f"custom-call(s8[{rows},2048]{{1,0}} %a)")
+            out.append(Event(name, t, 10_000.0))
+            t += 10_000.0
+        t += 1e6
+    return out
+
+
+def _roofline_ctx(events, decode_steps):
+    stats = {"decode_steps": decode_steps, "decode_kv_tokens": 1000,
+             "decode_rows": 20}
+    return {"stats": stats, "reference": reference(), "sizes": SIZES,
+            "layers": [("l", "quant_matmul", 64, 128, 1.0)],
+            "mix": {"engine": {"max_batch": 16}},
+            "peaks": {"hbm_byte_s": 1e9}, "trace": {"events": events}}
+
+
+def test_decode_roofline_divides_by_the_decode_calls_device_time():
+    ev = _events((4096, 5), (None, 2), (16, 3), (16, 4), (16, 2))
+    kinds = [k for k, _ in roofline_reader.calls(ev, 16)]
+    assert kinds == ["chunk", "other", "decode", "decode", "decode"]
+    ctx = _roofline_ctx(ev, decode_steps=3)
+    least = roofline_reader.least_bytes(ctx)
+    want = 100.0 * least / 1e9 / (9 * 10_000.0 * 1e-9)
+    assert roofline_reader.read(ctx) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("steps", [2, 4])
+def test_decode_roofline_is_silent_where_calls_and_steps_differ(steps):
+    ev = _events((4096, 5), (16, 3), (16, 4), (16, 2))
+    assert roofline_reader.read(_roofline_ctx(ev, steps)) is None
+
+
+def test_decode_roofline_finds_one_call_in_a_recorded_v5e_decode_step():
+    """The recorded zamba2 decode step (batch 32, bench_reduce's data) is
+    one call, of kernels on 32 rows: a decode call at batch 32, a chunk
+    call's rows at batch 16."""
+    rec = json.loads((DATA / "v5e_decode_events.json").read_text())
+    ev = [Event(e["name"], e["start_ns"], e["dur_ns"]) for e in rec]
+    assert {roofline_reader.kernel_rows(e.name) for e in ev} == {None, 32}
+    (got,) = roofline_reader.calls(ev, 32)
+    assert got == ("decode", devtrace.busy_ns(ev))
+    assert roofline_reader.calls(ev, 16)[0][0] == "chunk"
 
 
 def test_end_to_end_metrics_without_trace(root):

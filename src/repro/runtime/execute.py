@@ -647,6 +647,19 @@ def _entry_slots(entry):
             yield from _entry_slots(p)
 
 
+def _held_arrays(obj):
+    """Every device array reachable from a bound entry's attributes, found
+    without `_entry_slots`' field lists: what those lists must cover."""
+    if isinstance(obj, jax.Array):
+        yield obj
+    elif isinstance(obj, (list, tuple)):
+        for o in obj:
+            yield from _held_arrays(o)
+    elif isinstance(obj, (PreparedLayer,) + _STACKED_TYPES):
+        for v in vars(obj).values():
+            yield from _held_arrays(v)
+
+
 def _entry_arrays(entry):
     """Every device array a bound entry (plain or stacked) keeps alive."""
     for holder, attr in _entry_slots(entry):
@@ -825,14 +838,21 @@ class PlanSet:
         self._weights: List[jax.Array] = []
         self._slots: List[Tuple[Any, str, int]] = []
         index: Dict[int, int] = {}
-        for entry in {id(e): e for bp in self._variants.values()
-                      for e in bp.by_name.values()}.values():
+        entries = {id(e): e for bp in self._variants.values()
+                   for e in bp.by_name.values()}.values()
+        for entry in entries:
             for holder, attr in _entry_slots(entry):
                 a = getattr(holder, attr)
                 if id(a) not in index:
                     index[id(a)] = len(self._weights)
                     self._weights.append(a)
                 self._slots.append((holder, attr, index[id(a)]))
+        # an array the slots miss would be embedded in every step
+        missed = {id(a) for e in entries for a in _held_arrays(e)} - set(index)
+        if missed:
+            raise ExecutionError(f"{len(missed)} prepared arrays are held "
+                                 f"outside _entry_slots' fields and would "
+                                 f"not reach the steps as arguments")
 
     # ---- prepared arrays as arguments -----------------------------------
 

@@ -478,3 +478,15 @@ def test_traced_reads_the_given_arrays_then_restores(yi):
     with pytest.raises(Exception, match="prepared arrays given"):
         with backend.traced(weights[:-1]):
             pass
+
+
+@pytest.mark.parametrize("field", ["w_q", "sw"])
+def test_an_array_outside_the_slots_is_refused(yi, monkeypatch, field):
+    """A prepared array that `_entry_slots` does not list would be closed
+    over, and embedded, by every step: the bank refuses to bind."""
+    from repro.runtime import execute
+    cfg, params, target, _ = yi
+    monkeypatch.setattr(execute, "_PREP_ARRAY_FIELDS", tuple(
+        f for f in execute._PREP_ARRAY_FIELDS if f != field))
+    with pytest.raises(execute.ExecutionError, match="outside _entry_slots"):
+        PlannedBackend(lower(target, params=params), params)
